@@ -12,25 +12,33 @@ Prints ``name,us_per_call,derived`` CSV.
 """
 from __future__ import annotations
 
+import sys
 import time
 import traceback
 
 
-def main() -> None:
+def main() -> int:
+    """Run every benchmark; one that fails is recorded and the rest still
+    run, but the exit status is non-zero if any failed."""
     from benchmarks import (bench_dispatch, bench_hlo_counters, bench_load,
                             bench_payload, bench_pipeline, bench_ret,
                             bench_serving, roofline)
     print("name,us_per_call,derived")
+    failed = []
     for mod in (bench_dispatch, bench_payload, bench_ret, bench_pipeline,
                 bench_serving, bench_hlo_counters, bench_load, roofline):
         t0 = time.time()
         try:
             mod.run()
-        except Exception as e:  # keep the harness going; record the failure
+        except Exception as e:
             print(f"{mod.__name__}_FAILED,0.0,{e!r}")
             traceback.print_exc()
+            failed.append(mod.__name__)
         print(f"# {mod.__name__} took {time.time() - t0:.1f}s", flush=True)
+    if failed:
+        print(f"# FAILED: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

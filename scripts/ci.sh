@@ -10,11 +10,11 @@ echo "== tier-1: pytest (slow-marked tests excluded; --runslow adds them) =="
 python -m pytest -x -q
 
 echo "== smoke: continuous-batching serve (open-loop) =="
-python -m repro.launch.serve --preset nss_shortcut --load open \
+python -m repro.launch.serve --smoke --preset nss_shortcut --load open \
     --requests 4 --slots 2 --prompt-len 16 --gen-len 16
 
 echo "== smoke: paged KV engine (open-loop, shared prefix) =="
-python -m repro.launch.serve --preset nss_shortcut --load open \
+python -m repro.launch.serve --smoke --preset nss_shortcut --load open \
     --requests 4 --slots 2 --prompt-len 16 --gen-len 16 \
     --kv paged --block-size 8 --shared-prefix-len 8
 
@@ -34,34 +34,34 @@ echo "          plus the int8 cells over sharded scale tables) =="
 python scripts/paged_smoke.py --chunked --swap --spec-decode --async-swap --fleet --mesh 1,2 --trace --kv-dtype int8
 
 echo "== smoke: chunked-prefill serve launcher (open-loop) =="
-python -m repro.launch.serve --preset nss_shortcut --load open \
+python -m repro.launch.serve --smoke --preset nss_shortcut --load open \
     --requests 4 --slots 2 --prompt-len 16 --gen-len 16 \
     --kv paged --block-size 8 --chunked --budget 16
 
 echo "== smoke: swap-preemption serve launcher (pool pressure, host tier) =="
-python -m repro.launch.serve --preset nss_shortcut --load closed \
+python -m repro.launch.serve --smoke --preset nss_shortcut --load closed \
     --requests 4 --slots 2 --prompt-len 8 --gen-len 12 --decode-steps 4 \
     --kv paged --block-size 8 --num-blocks 5 --preempt swap
 
 echo "== smoke: quantized-KV serve launcher (int8 blocks, swap pressure) =="
-python -m repro.launch.serve --preset nss_shortcut --load closed \
+python -m repro.launch.serve --smoke --preset nss_shortcut --load closed \
     --requests 4 --slots 2 --prompt-len 8 --gen-len 12 --decode-steps 4 \
     --kv paged --block-size 8 --num-blocks 5 --preempt swap --kv-dtype int8
 
 echo "== smoke: speculative-decode serve launcher (n-gram drafts) =="
-python -m repro.launch.serve --preset nss_shortcut --load closed \
+python -m repro.launch.serve --smoke --preset nss_shortcut --load closed \
     --requests 4 --slots 2 --prompt-len 18 --gen-len 14 --decode-steps 3 \
     --kv paged --block-size 8 --spec-decode ngram --spec-width 6
 
 echo "== smoke: fleet serve launcher (2 replicas, disaggregated) =="
-python -m repro.launch.fleet --preset nss_shortcut --load open \
+python -m repro.launch.fleet --smoke --preset nss_shortcut --load open \
     --requests 4 --slots 2 --prompt-len 16 --gen-len 8 --decode-steps 4 \
     --replicas 2 --disaggregate 1 --block-size 8
 
 echo "== smoke: telemetry — traced chunked launcher + trace_summary =="
 CI_TRACE_DIR="$(mktemp -d)"
 trap 'rm -rf "$CI_TRACE_DIR"' EXIT
-python -m repro.launch.serve --preset nss_shortcut --load open \
+python -m repro.launch.serve --smoke --preset nss_shortcut --load open \
     --requests 4 --slots 2 --prompt-len 16 --gen-len 16 \
     --kv paged --block-size 8 --chunked --budget 16 \
     --trace "$CI_TRACE_DIR/trace.json" \

@@ -4,8 +4,4 @@ Each kernel ships three artifacts (assignment contract):
   <name>.py -- pl.pallas_call + explicit BlockSpec VMEM tiling
   ops.py    -- jit'd public wrappers (backend dispatch, mask precompute)
   ref.py    -- pure-jnp oracles, asserted against in tests
-
-Each kernel module aliases the Mosaic compiler-params dataclass locally
-(jax < 0.5 names it TPUCompilerParams) so importing this package never
-mutates jax state and the jnp oracles stay importable without pallas-tpu.
 """

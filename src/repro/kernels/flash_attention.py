@@ -26,8 +26,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names the Mosaic compiler-params dataclass TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -176,7 +174,7 @@ def _flash_impl(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 128), jnp.float32),   # running denom
             pltpu.VMEM((bq, dhp), jnp.float32),   # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qT, kT, vT)

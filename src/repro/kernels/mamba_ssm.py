@@ -19,9 +19,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names the Mosaic compiler-params dataclass TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _mamba_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, o_ref, h_ref, *,
                   cs: int, ns: int):
@@ -84,7 +81,7 @@ def mamba_scan(x, dt, A, Bv, Cv, *, chunk: int = 64, di_tile: int = 256,
         out_specs=pl.BlockSpec((1, cs, dit), lambda b, d, s: (b, s, d)),
         out_shape=jax.ShapeDtypeStruct((B, Sp, dip), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dit, ds), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, Bv, Cv, A)

@@ -1,143 +1,35 @@
 """Paged one-token decode attention: flash-decode over a block-table cache.
 
 The serving engine's paged KV backend keeps every slot's cache as a chain of
-fixed-size blocks in one physical pool (``repro.serve.paging``). This kernel
-is the slot-aware decode kernel re-addressed through that indirection: the
-grid's inner axis walks the slot's *logical* blocks and a scalar-prefetched
+fixed-size blocks in one physical pool (``repro.serve.paging``). A decode
+step is a one-token chunk at the slot's current position, so this is the
+W = 1 case of the paged prefill kernel (``repro.kernels.paged_prefill``):
+the grid's inner axis walks the slot's *logical* blocks, a scalar-prefetched
 block table translates each step to a physical pool row in the BlockSpec
-index map — the gather happens in the DMA engine, never materialized in HBM.
-The online-softmax body is reused verbatim from ``decode_attention``: the
-accumulation never cared where a KV tile was fetched from, only which lanes
-the mask keeps.
+index map, and the per-row position rides the same scalar-prefetch lane, so
+the live-lane mask ``k_pos <= pos`` is formed from grid coordinates and no
+mask array is read from HBM.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names the Mosaic compiler-params dataclass TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-from repro.kernels.decode_attention import NEG_INF, _decode_kernel
-
-
-def _paged_kernel(tbl_ref, q_ref, k_ref, v_ref, msk_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale: float, nt: int):
-    # the block table was consumed by the index maps; the body is the shared
-    # flash-decode accumulation
-    _decode_kernel(q_ref, k_ref, v_ref, msk_ref, o_ref, m_ref, l_ref, acc_ref,
-                   scale=scale, nt=nt)
-
-
-def _paged_kernel_quant(tbl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, msk_ref,
-                        o_ref, m_ref, l_ref, acc_ref, *, scale: float,
-                        nt: int):
-    # fused-dequant variant: the KV tiles arrive quantized (int8 / fp8) and
-    # the per-(block, head) scale rides the same scalar-prefetch indirection
-    # as the block table — ks/vs BlockSpecs index (tbl[b, i], h), so each
-    # program sees exactly its tile's scale as a (1, 1) scalar. Decode back
-    # to f32 here, in VMEM, then run the unchanged flash-decode accumulation.
-    ti = pl.program_id(2)
-
-    @pl.when(ti == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                # (G, dh)
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]  # (bs, dh), dequant
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
-    live = msk_ref[0] != 0                             # (bs,)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = jnp.where(live[None, :], s, NEG_INF)           # (G, bs)
-    m_prev = m_ref[:, 0]
-    m_cur = jnp.maximum(m_prev, s.max(axis=-1))
-    corr = jnp.exp(m_prev - m_cur)
-    p = jnp.where(live[None, :], jnp.exp(s - m_cur[:, None]), 0.0)
-    l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[:, 0] = m_cur
-
-    @pl.when(ti == nt - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+from repro.kernels.paged_prefill import paged_prefill_attention
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, kp, vp, tables, valid, ks=None, vs=None, *,
+def paged_decode_attention(q, kp, vp, tables, pos, ks=None, vs=None, *,
                            interpret: bool = False):
     """q:(B,HQ,dh); kp,vp:(P+1,bs,HKV,dh) physical pools; tables:(B,nb)
-    int32 logical->physical block map; valid:(B, nb*bs) bool. -> (B,HQ,dh).
+    int32 logical->physical block map; pos:(B,) int32 position of each
+    row's query (logical positions ``<= pos`` are live). -> (B,HQ,dh).
 
-    Each (batch, kv-head) program walks the slot's nb logical blocks; the
-    index map reads ``tables[b, i]`` (scalar-prefetched) to pick the pool
-    row, so dead slots pointing at the trash row and garbage tails are
-    simply lanes the mask zeroes out.
-
-    ``ks``/``vs`` (P+1, HKV) f32 mark the pools as per-block quantized:
-    each tile's scale is fetched through the same table indirection and the
-    dequant fuses into the flash-decode body (``_paged_kernel_quant``).
+    Dead slots point at the trash row; whatever they compute is discarded.
+    ``ks``/``vs`` (P+1, HKV) f32 mark the pools as per-block quantized: each
+    tile's scale is fetched through the same table indirection and the
+    dequant fuses into the kernel.
     """
-    B, HQ, dh = q.shape
-    P1, bs, HKV = kp.shape[0], kp.shape[1], kp.shape[2]
-    nb = tables.shape[1]
-    G = HQ // HKV
-    scale = 1.0 / math.sqrt(dh)
-    kT = kp.transpose(0, 2, 1, 3)                     # (P+1, HKV, bs, dh)
-    vT = vp.transpose(0, 2, 1, 3)
-    dhp = (-dh) % 128
-    if dhp:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, dhp)))
-        kT = jnp.pad(kT, ((0, 0), (0, 0), (0, 0), (0, dhp)))
-        vT = jnp.pad(vT, ((0, 0), (0, 0), (0, 0), (0, dhp)))
-    dhf = dh + dhp
-    qg = q.reshape(B, HKV, G, dhf)
-    mask = valid.astype(jnp.int32)                    # (B, nb*bs)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, G, dhf), lambda b, h, i, tbl: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, dhf),
-                     lambda b, h, i, tbl: (tbl[b, i], h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, dhf),
-                     lambda b, h, i, tbl: (tbl[b, i], h, 0, 0)),
-    ]
-    operands = [qg, kT, vT]
-    kernel = _paged_kernel
-    if ks is not None:
-        # per-(block, head) scale tables ride the same table indirection
-        in_specs += [pl.BlockSpec((1, 1), lambda b, h, i, tbl: (tbl[b, i], h)),
-                     pl.BlockSpec((1, 1), lambda b, h, i, tbl: (tbl[b, i], h))]
-        operands += [ks, vs]
-        kernel = _paged_kernel_quant
-    in_specs.append(pl.BlockSpec((1, bs), lambda b, h, i, tbl: (b, i)))
-    operands.append(mask)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, HKV, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, dhf), lambda b, h, i, tbl: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, dhf), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(kernel, scale=scale, nt=nb),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, HKV, G, dhf), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(tables, *operands)
-    return out.reshape(B, HQ, dhf)[..., :dh]
+    return paged_prefill_attention(q[:, None], kp, vp, tables, pos, ks, vs,
+                                   interpret=interpret)[:, 0]

@@ -4,8 +4,7 @@ The chunked-prefill serve step (``repro.core.step.build_serve_step``) hands
 every slot a variable-length prompt chunk whose K/V was just scattered into
 the slot's physical blocks. This kernel computes the chunk's queries against
 the slot's whole resident prefix — the rectangular (W queries x resident
-keys) generalization of ``paged_decode``, and the roadmap's missing paged
-prefill kernel:
+keys) generalization of ``paged_decode``, which is its W = 1 case:
 
   * the grid's inner axis walks the slot's *logical* blocks and a
     scalar-prefetched block table translates each step to a physical pool
@@ -17,7 +16,10 @@ prefill kernel:
     query, later chunk positions are masked per query row. Garbage beyond a
     row's resident end always sits at positions above every real query, so
     it is masked by the same comparison (padding query rows are discarded
-    by the caller).
+    by the caller);
+  * quantized pools (int8 / fp8) carry per-(block, head) f32 scales. Each
+    tile's scale pair is fetched into SMEM through the same ``tbl[b, i]``
+    indirection as the tile itself, and the dequant fuses into the body.
 
 All W queries of one (batch, kv-head) program are processed together
 (W·G x bs score tiles), so each KV block is read exactly once per head —
@@ -34,89 +36,29 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names the Mosaic compiler-params dataclass TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-from repro.kernels.decode_attention import NEG_INF
+from repro.kernels.decode_attention import flash_step
 
 
-def _paged_prefill_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-                          m_ref, l_ref, acc_ref, *, scale: float, nt: int,
-                          bs: int, G: int):
+def _paged_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, *refs,
+                  scale: float, nt: int, bs: int, G: int, quant: bool):
+    if quant:
+        ks_ref, vs_ref, *refs = refs
+    o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
     q = q_ref[0, 0].astype(jnp.float32)                # (W*G, dh)
     k = k_ref[0, 0].astype(jnp.float32)                # (bs, dh)
     v = v_ref[0, 0].astype(jnp.float32)
+    if quant:                                          # dequant in VMEM
+        k = k * ks_ref[0, 0, 0, 0]
+        v = v * vs_ref[0, 0, 0, 0]
     WG = q.shape[0]
-    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * scale
     # causal mask from grid coordinates: query w sits at start[b] + w, key
     # lane j of logical block i sits at i*bs + j
     q_pos = start_ref[b] + lax.broadcasted_iota(jnp.int32, (WG, bs), 0) // G
     k_pos = i * bs + lax.broadcasted_iota(jnp.int32, (WG, bs), 1)
-    live = k_pos <= q_pos
-    s = jnp.where(live, s, NEG_INF)
-    m_prev = m_ref[:, 0]
-    m_cur = jnp.maximum(m_prev, s.max(axis=-1))
-    corr = jnp.exp(m_prev - m_cur)
-    p = jnp.where(live, jnp.exp(s - m_cur[:, None]), 0.0)
-    l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[:, 0] = m_cur
-
-    @pl.when(i == nt - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-
-
-def _paged_prefill_kernel_quant(tbl_ref, start_ref, q_ref, k_ref, v_ref,
-                                ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-                                *, scale: float, nt: int, bs: int, G: int):
-    # fused-dequant variant: quantized KV tiles plus their per-(block, head)
-    # scales, fetched through the same ``tbl[b, i]`` indirection as the
-    # tiles themselves. Identical flash accumulation, f32 restored in VMEM.
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                # (W*G, dh)
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]  # (bs, dh), dequant
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
-    WG = q.shape[0]
-    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * scale
-    q_pos = start_ref[b] + lax.broadcasted_iota(jnp.int32, (WG, bs), 0) // G
-    k_pos = i * bs + lax.broadcasted_iota(jnp.int32, (WG, bs), 1)
-    live = k_pos <= q_pos
-    s = jnp.where(live, s, NEG_INF)
-    m_prev = m_ref[:, 0]
-    m_cur = jnp.maximum(m_prev, s.max(axis=-1))
-    corr = jnp.exp(m_prev - m_cur)
-    p = jnp.where(live, jnp.exp(s - m_cur[:, None]), 0.0)
-    l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[:, 0] = m_cur
-
-    @pl.when(i == nt - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    flash_step(q, k, v, k_pos <= q_pos, o_ref, m_ref, l_ref, acc_ref,
+               scale=scale, step=i, nt=nt)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -131,7 +73,7 @@ def paged_prefill_attention(q, kp, vp, tables, start, ks=None, vs=None, *,
     length produce garbage the caller discards.
 
     ``ks``/``vs`` (P+1, HKV) f32 mark the pools as per-block quantized: the
-    dequant fuses into the flash body (``_paged_prefill_kernel_quant``).
+    dequant fuses into the flash body.
     """
     B, W, HQ, dh = q.shape
     bs, HKV = kp.shape[1], kp.shape[2]
@@ -160,15 +102,15 @@ def paged_prefill_attention(q, kp, vp, tables, start, ks=None, vs=None, *,
                      lambda b, h, i, tbl, st: (tbl[b, i], h, 0, 0)),
     ]
     operands = [qg, kT, vT]
-    kernel = _paged_prefill_kernel
-    if ks is not None:
-        # per-(block, head) scale tables ride the same table indirection
-        in_specs += [
-            pl.BlockSpec((1, 1), lambda b, h, i, tbl, st: (tbl[b, i], h)),
-            pl.BlockSpec((1, 1), lambda b, h, i, tbl, st: (tbl[b, i], h)),
-        ]
-        operands += [ks, vs]
-        kernel = _paged_prefill_kernel_quant
+    quant = ks is not None
+    if quant:
+        # per-(block, head) scales ride the same table indirection, as
+        # scalars in SMEM; the trailing unit dims make each (1, 1) tile span
+        # the array's last two dims, as Mosaic's tiling rule asks
+        in_specs += [pl.BlockSpec(
+            (1, 1, 1, 1), lambda b, h, i, tbl, st: (tbl[b, i], h, 0, 0),
+            memory_space=pltpu.SMEM)] * 2
+        operands += [ks[..., None, None], vs[..., None, None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -183,10 +125,11 @@ def paged_prefill_attention(q, kp, vp, tables, start, ks=None, vs=None, *,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(kernel, scale=scale, nt=nb, bs=bs, G=G),
+        functools.partial(_paged_kernel, scale=scale, nt=nb, bs=bs, G=G,
+                          quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, HKV, W * G, dhf), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables, start.astype(jnp.int32), *operands)

@@ -21,9 +21,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names the Mosaic compiler-params dataclass TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *, cs: int):
     si = pl.program_id(2)
@@ -85,7 +82,7 @@ def rwkv_scan(r, k, v, w, u, *, chunk: int = 256, interpret: bool = False):
         out_specs=pl.BlockSpec((1, 1, cs, hd), lambda b, h, s: (b, h, s, 0)),
         out_shape=jax.ShapeDtypeStruct((B, nh, Sp, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(rT, kT, vT, wT, u)

@@ -7,6 +7,10 @@ difference from the uniform kernel is the validity mask: per-slot ``(B, T)``
 instead of shared ``(T,)``, so the mask BlockSpec is indexed by the batch grid
 axis. The kernel body itself is reused verbatim from ``decode_attention`` —
 the online-softmax accumulation never cared which row the mask came from.
+
+The mask is laid out as ``(B, nt, 1, bt)``: each grid step's ``(1, bt)`` tile
+then spans the array's last two dims whole, which is what Mosaic's tiling
+rule asks of a block whose last dims are not (8, 128) multiples.
 """
 from __future__ import annotations
 
@@ -17,9 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax < 0.5 names the Mosaic compiler-params dataclass TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 from repro.kernels.decode_attention import _decode_kernel
 
@@ -46,8 +47,9 @@ def slot_decode_attention(q, k, v, valid, *, block_t: int = 512,
         qp = q
     dhf = dh + dhp
     qg = qp.reshape(B, HKV, G, dhf)
-    mask = jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, pad)))  # (B, T+pad)
     nt = (T + pad) // bt
+    mask = jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, pad))) \
+        .reshape(B, nt, 1, bt)
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, nt=nt),
@@ -56,7 +58,7 @@ def slot_decode_attention(q, k, v, valid, *, block_t: int = 512,
             pl.BlockSpec((1, 1, G, dhf), lambda b, h, ti: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bt, dhf), lambda b, h, ti: (b, h, ti, 0)),
             pl.BlockSpec((1, 1, bt, dhf), lambda b, h, ti: (b, h, ti, 0)),
-            pl.BlockSpec((1, bt), lambda b, h, ti: (b, ti)),
+            pl.BlockSpec((1, 1, 1, bt), lambda b, h, ti: (b, ti, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, dhf), lambda b, h, ti: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, HKV, G, dhf), q.dtype),
@@ -65,7 +67,7 @@ def slot_decode_attention(q, k, v, valid, *, block_t: int = 512,
             pltpu.VMEM((G, 128), jnp.float32),
             pltpu.VMEM((G, dhf), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, kT, vT, mask)

@@ -271,8 +271,6 @@ def analyze_cell(arch: str, shape_name: str, mesh: Mesh,
         rec["memory"] = {"error": str(e)}
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax < 0.5 returns [dict]
-        ca = ca[0] if ca else {}
     rec["xla_flops_per_device"] = float(ca.get("flops", 0.0))
     rec["xla_bytes_per_device"] = float(ca.get("bytes accessed", 0.0))
 

@@ -4,13 +4,17 @@ router, with optional prefill/decode disaggregation.
 UKL's deployment story scaled out: many specialized cells, one
 orchestrator, shared resources (MultiK / uTNT in PAPERS.md). Examples:
 
-  python -m repro.launch.fleet --replicas 2                  # colocated
-  python -m repro.launch.fleet --replicas 4 --disaggregate 2 # 2 prefill
-      # cells stream chunked prefill, 2 decode cells receive the finished
-      # KV chains over the swap lane and never stall on a long prompt
-  python -m repro.launch.fleet --replicas 2 --shared-prefix-len 16
+  python -m repro.launch.fleet --smoke --replicas 2          # colocated
+  python -m repro.launch.fleet --smoke --replicas 4 --disaggregate 2
+      # 2 prefill cells stream chunked prefill, 2 decode cells receive the
+      # finished KV chains over the swap lane and never stall on a long
+      # prompt
+  python -m repro.launch.fleet --smoke --replicas 2 --shared-prefix-len 16
       # a prefix prefilled by either replica warms both via the shared
       # host-tier prefix store
+
+``--smoke`` serves the config's CPU-sized reduction; without it every
+replica runs the arch at its published width.
 
 The report is the fleet-aggregate ``fleet_report``: percentiles over the
 pooled completions, counters summed across replicas, handoff and
@@ -163,18 +167,26 @@ def main(argv=None) -> int:
     p.add_argument("--prompt-len", type=int, default=32)
     p.add_argument("--gen-len", type=int, default=32)
     p.add_argument("--requests", type=int, default=8)
-    p.add_argument("--scale", type=float, default=1.0)
+    p.add_argument("--smoke", action="store_true",
+                   help="serve the reduced same-family config (CPU-sized); "
+                        "without it the arch runs at its published width")
+    p.add_argument("--scale", type=float, default=1.0,
+                   help="width multiplier on the --smoke config")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--report-json", default=None)
     args = p.parse_args(argv)
+    if args.scale != 1.0 and not args.smoke:
+        p.error("--scale resizes the --smoke config only")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rep = run_fleet_engine(
         args.arch, args.preset, replicas=args.replicas,
         disaggregate=args.disaggregate, n_slots=args.slots,
         prompt_len=args.prompt_len, gen_len=args.gen_len,
         requests=args.requests, load=args.load, rate=args.rate,
         concurrency=args.concurrency, decode_steps=args.decode_steps,
-        scale=args.scale, seed=args.seed, kv=args.kv,
+        smoke=args.smoke, scale=args.scale, seed=args.seed, kv=args.kv,
         block_size=args.block_size, num_blocks=args.num_blocks,
         admit_cap=args.admit_cap,
         shared_host_blocks=args.shared_host_blocks,

@@ -10,20 +10,27 @@ Two paths share one model/linkage setup:
                     block-table subsystem (demand allocation, CoW prefix
                     sharing, block watermark reporting).
 
-      python -m repro.launch.serve --preset nss_shortcut --load open
-      python -m repro.launch.serve --preset ret_byp --load closed \
+      python -m repro.launch.serve --smoke --preset nss_shortcut --load open
+      python -m repro.launch.serve --smoke --preset ret_byp --load closed \
           --slots 8 --requests 32
-      python -m repro.launch.serve --preset nss_shortcut --kv paged \
+      python -m repro.launch.serve --smoke --preset nss_shortcut --kv paged \
           --block-size 16 --shared-prefix-len 16 --bucket-prompts
-      python -m repro.launch.serve --preset nss_shortcut --kv paged \
-          --preempt swap --prefix-cache /tmp/prefix.npz   # two-tier KV:
+      python -m repro.launch.serve --smoke --preset nss_shortcut --kv paged \
+          --preempt swap --prefix-cache prefix.npz   # two-tier KV:
           # swap-out preemption + restart-persistent prefix cache
       XLA_FLAGS=--xla_force_host_platform_device_count=2 \
-          python -m repro.launch.serve --preset nss_shortcut --kv paged \
-          --mesh 1,2      # sharded: TP weights + per-shard KV residency
-      python -m repro.launch.serve --preset nss_shortcut --kv paged \
+          python -m repro.launch.serve --smoke --preset nss_shortcut \
+          --kv paged --mesh 1,2   # sharded: TP weights + per-shard KV
+      python -m repro.launch.serve --smoke --preset nss_shortcut --kv paged \
           --spec-decode ngram --spec-width 6   # self-speculation: n-gram
           # drafts verified in one chunk-shaped program per step
+
+                    ``--smoke`` serves the config's CPU-sized reduction
+                    (``ArchConfig.smoke``); without it the model runs at its
+                    published width, which is what a TPU serves:
+
+      python -m repro.launch.serve --kv paged --chunked --slots 8 \
+          --prompt-len 512 --gen-len 64 --load closed
 
   sequential        the original one-request-at-a-time loop (``--load seq``,
                     also ``run_server`` for benchmarks): the baseline the
@@ -69,6 +76,52 @@ def _setup(arch: str, preset_name: str, *, smoke: bool = True,
         opts = lk.model_options(opts, on_tpu=jax.default_backend() == "tpu")
     params = init_params(jax.random.PRNGKey(seed), cfg)
     return cfg, lk, opts, params
+
+
+def teacher_forced_logits(cfg, opts, params, prompt, forced, *,
+                          block_size: int = 16, chunk: int = 256, mesh=None):
+    """Logits of one request through the paged, chunked serving passes.
+
+    The prompt goes through the serve-chunk pass in ``chunk``-token pieces,
+    then each ``forced`` token is fed as one paged decode step, whatever the
+    model would have picked — so two paths (kernels vs reference, one device
+    vs a mesh) see identical inputs and their logits compare position by
+    position. Returns float32 ``(1 + len(forced), vocab)``: the prompt's
+    last-position logits, then each decode step's. With ``mesh`` the weights
+    and block pools are placed as the engine places them.
+    """
+    import jax
+    import jax.numpy as jnp
+    from repro.models import decode_step_paged, serve_chunk_step_paged
+    from repro.serve.paging import init_paged_cache
+
+    nb = -(-(len(prompt) + len(forced)) // block_size)
+    max_len = nb * block_size
+    cache = init_paged_cache(cfg, nb, block_size, 1, opts.dtype)
+    tables = jnp.arange(nb, dtype=jnp.int32)[None]
+    if mesh is not None:
+        from repro.sharding.rules import ArchSharding, named
+        sh = ArchSharding(cfg, mesh)
+        params = jax.device_put(params,
+                                named(mesh, sh.serve_param_specs(params)))
+        cache = jax.device_put(cache,
+                               named(mesh, sh.serve_paged_cache_specs(cache)))
+        opts = dataclasses.replace(opts, mesh=mesh)
+    chunk_fn = jax.jit(lambda p, c, t, s, n: serve_chunk_step_paged(
+        p, c, t, tables, s, n, cfg, opts, max_len))
+    dec_fn = jax.jit(lambda p, c, t: decode_step_paged(
+        p, c, t, tables, cfg, opts, max_len))
+    for s in range(0, len(prompt), chunk):
+        piece = np.asarray(prompt[s:s + chunk], np.int32)
+        toks = np.zeros((1, chunk), np.int32)
+        toks[0, :len(piece)] = piece
+        logits, cache = chunk_fn(params, cache, toks, np.array([s], np.int32),
+                                 np.array([len(piece)], np.int32))
+    out = [logits[0]]
+    for t in forced:
+        logits, cache = dec_fn(params, cache, np.array([t], np.int32))
+        out.append(logits[0])
+    return np.asarray(jnp.stack(out), np.float32)
 
 
 def run_engine(arch: str, preset_name: str, *, n_slots: int = 4,
@@ -387,22 +440,31 @@ def main(argv=None) -> int:
     p.add_argument("--prompt-len", type=int, default=32)
     p.add_argument("--gen-len", type=int, default=32)
     p.add_argument("--requests", type=int, default=8)
-    p.add_argument("--scale", type=float, default=1.0)
+    p.add_argument("--smoke", action="store_true",
+                   help="serve the reduced same-family config (CPU-sized); "
+                        "without it the arch runs at its published width")
+    p.add_argument("--scale", type=float, default=1.0,
+                   help="width multiplier on the --smoke config")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--report-json", default=None)
     args = p.parse_args(argv)
+    if args.scale != 1.0 and not args.smoke:
+        p.error("--scale resizes the --smoke config only")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.load == "seq":
         rep = run_server(args.arch, args.preset, batch=args.batch,
                          prompt_len=args.prompt_len, gen_len=args.gen_len,
-                         requests=args.requests, scale=args.scale,
-                         seed=args.seed)
+                         requests=args.requests, smoke=args.smoke,
+                         scale=args.scale, seed=args.seed)
     else:
         rep = run_engine(args.arch, args.preset, n_slots=args.slots,
                          prompt_len=args.prompt_len, gen_len=args.gen_len,
                          requests=args.requests, load=args.load,
                          rate=args.rate, concurrency=args.concurrency,
-                         decode_steps=args.decode_steps, scale=args.scale,
+                         decode_steps=args.decode_steps,
+                         smoke=args.smoke, scale=args.scale,
                          seed=args.seed, kv=args.kv,
                          block_size=args.block_size,
                          num_blocks=args.num_blocks,

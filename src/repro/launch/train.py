@@ -39,12 +39,14 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.core import LinkageConfig, build_train_step, init_train_state, preset
     from repro.data import DataConfig, Pipeline
     from repro.models import ModelOptions
     from repro.optim import AdamWConfig
     from repro.runtime import DriverConfig, train
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

@@ -42,6 +42,8 @@ class ModelOptions:
     scan_blocks: bool = True        # lax.scan over repeated blocks
     logit_chunk: int = 0            # 0 = whole-seq logits; else chunked xent
     fused_norm: bool = False        # use pallas fused rmsnorm (shortcut)
+    mesh: Any = None                # serving mesh: pallas kernels run per
+                                    # shard (XLA cannot partition them)
     moe_group: int = 4096           # MoE routing-group size (tokens)
     # activation sharding constraint axes (None = let GSPMD propagate).
     # e.g. ("data",) or ("pod","data"): batch dim of every residual-stream
@@ -126,7 +128,7 @@ _rmsnorm_bf16_grad.defvjp(_rmsnorm_bf16_fwd, _rmsnorm_bf16_bwd)
 def rmsnorm(params: Params, x: jax.Array, eps: float, opts: ModelOptions) -> jax.Array:
     if opts.fused_norm:
         from repro.kernels import ops as kops
-        return kops.rmsnorm(x, params["scale"], eps=eps)
+        return kops.rmsnorm(x, params["scale"], eps=eps, mesh=opts.mesh)
     if opts.norm_bf16_grad:
         return _rmsnorm_bf16_grad(params["scale"], x, eps)
     return _rmsnorm_raw(params["scale"], x, eps)
@@ -325,7 +327,8 @@ def attention(params: Params, x: jax.Array, cfg: ArchConfig, spec: LayerSpec,
                             causal_skip=opts.causal_skip, **kwargs)
     elif opts.attn_impl == "pallas":
         from repro.kernels import ops as kops
-        out = kops.flash_attention(q, k, v, causal=True, window=window)
+        out = kops.flash_attention(q, k, v, causal=True, window=window,
+                                   mesh=opts.mesh)
     else:
         raise ValueError(opts.attn_impl)
     y = out.reshape(B, S, -1) @ params["wo"].astype(x.dtype)
@@ -439,7 +442,7 @@ def attention_decode_slots(params: Params, x: jax.Array, cache: Params,
     if opts.attn_impl == "pallas":
         from repro.kernels import ops as kops
         out = kops.slot_decode_attention(q, ck, cv, slot_pos, pos,
-                                         window=window)
+                                         window=window, mesh=opts.mesh)
     else:
         valid = (slot_pos <= pos[:, None]) & (slot_pos >= 0)   # (B,T)
         if window > 0:
@@ -512,7 +515,8 @@ def attention_decode_paged(params: Params, x: jax.Array, cache: Params,
 
     if opts.attn_impl == "pallas":
         from repro.kernels import ops as kops
-        out = kops.paged_decode_attention(q, kp, vp, tables, pos, ks, vs)
+        out = kops.paged_decode_attention(q, kp, vp, tables, pos, ks, vs,
+                                          mesh=opts.mesh)
     else:
         # gather the logical view: (B, nb, bs, ...) -> (B, max_len, ...).
         # Same shapes, values and masks as the slotted dense row, so the
@@ -650,7 +654,8 @@ def attention_serve_chunk_paged(params: Params, x: jax.Array, cache: Params,
 
     if opts.attn_impl == "pallas":
         from repro.kernels import ops as kops
-        out = kops.paged_prefill_attention(q, kp, vp, tables, start, ks, vs)
+        out = kops.paged_prefill_attention(q, kp, vp, tables, start, ks, vs,
+                                           mesh=opts.mesh)
     else:
         # gather fallback: assemble each row's logical view and mask by
         # position — same shapes and reductions as the dense chunk path

@@ -109,6 +109,9 @@ class ServeEngine:
             raise ValueError("serving engine takes token ids, not embeddings")
         if n_slots < 1:
             raise ValueError("serving engine needs n_slots >= 1")
+        if mesh is not None:
+            # the shortcut kernels run per shard on the serving mesh
+            opts = dataclasses.replace(opts, mesh=mesh)
         self.cfg = cfg
         self.params = params
         self.opts = opts

@@ -171,8 +171,8 @@ def test_paged_decode_kernel_parity(P1, bs, nb, B, HQ, HKV, dh, lives, dt):
     valid = np.zeros((B, nb * bs), bool)
     for b, live in enumerate(lives):
         valid[b, :live] = True
-    out = paged_decode_attention(q, kp, vp, tables, jnp.asarray(valid),
-                                 interpret=True)
+    pos = jnp.asarray(np.array(lives, np.int32) - 1)    # last live lane
+    out = paged_decode_attention(q, kp, vp, tables, pos, interpret=True)
     ref = kref.paged_decode_attention_ref(q, kp, vp, tables,
                                           jnp.asarray(valid))
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -244,8 +244,7 @@ def test_paged_prefill_width_one_matches_decode_kernel():
     tables = jnp.asarray(np.array([[0, 2, 5], [4, 1, 6]], np.int32))
     pos = jnp.asarray(np.array([12, 0], np.int32))      # mid-block + fresh
     out_pf = paged_prefill_attention(q, kp, vp, tables, pos, interpret=True)
-    valid = jnp.arange(nb * bs, dtype=jnp.int32)[None] <= pos[:, None]
-    out_dec = paged_decode_attention(q[:, 0], kp, vp, tables, valid,
+    out_dec = paged_decode_attention(q[:, 0], kp, vp, tables, pos,
                                      interpret=True)
     np.testing.assert_allclose(np.asarray(out_pf[:, 0]), np.asarray(out_dec),
                                atol=2e-5, rtol=2e-5)
@@ -405,7 +404,8 @@ def test_paged_decode_quantized_parity(P1, bs, nb, B, HQ, HKV, dh, lives,
     for b, live in enumerate(lives):
         valid[b, :live] = True
     valid = jnp.asarray(valid)
-    out = paged_decode_attention(q, kp, vp, tables, valid, ks, vs,
+    pos = jnp.asarray(np.array(lives, np.int32) - 1)    # last live lane
+    out = paged_decode_attention(q, kp, vp, tables, pos, ks, vs,
                                  interpret=True)
     # fused kernel == quantize-then-dequant oracle (same math, fused fetch)
     ref_q = kref.paged_decode_attention_ref(q, kp, vp, tables, valid,
@@ -465,8 +465,7 @@ def test_paged_prefill_quantized_width_one_matches_decode_kernel():
     pos = jnp.asarray(np.array([12, 0], np.int32))
     out_pf = paged_prefill_attention(q, kp, vp, tables, pos, ks, vs,
                                      interpret=True)
-    valid = jnp.arange(nb * bs, dtype=jnp.int32)[None] <= pos[:, None]
-    out_dec = paged_decode_attention(q[:, 0], kp, vp, tables, valid, ks, vs,
+    out_dec = paged_decode_attention(q[:, 0], kp, vp, tables, pos, ks, vs,
                                      interpret=True)
     np.testing.assert_allclose(np.asarray(out_pf[:, 0]), np.asarray(out_dec),
                                atol=2e-5, rtol=2e-5)

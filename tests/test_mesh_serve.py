@@ -447,3 +447,89 @@ def test_mesh_spec_identity_matrix(params, kv, preset_name, mesh_name):
                           spec_decode="ngram", spec_width=6)
     assert got == plain, f"spec {kv}/{preset_name}/{mesh_name} != plain"
     assert eng.utilization()["spec_steps"] > 0
+
+
+# ---------------------------------------------------------------------------
+# The shortcut kernels on a mesh. XLA cannot partition a Mosaic call, so on
+# a mesh every kernel runs per shard under shard_map, heads split over
+# "model" in whole GQA groups. Interpret mode runs the same kernel bodies on
+# CPU, so the wrapping is checked here (tests/test_tpu_compile.py checks it
+# compiles for four v5e chips).
+# ---------------------------------------------------------------------------
+
+# four KV heads, as TinyLlama has, so the heads split over model = 2
+KV4 = dataclasses.replace(CFG, n_heads=8, n_kv_heads=4, d_head=8)
+PALLAS_OPTS = dataclasses.replace(REF_OPTS, attn_impl="pallas",
+                                  scan_impl="pallas", fused_norm=True)
+
+
+def _kernel_call(name, mesh):
+    from repro.kernels import kv_quant, ops
+    B, W, HQ, HKV, dh, bs, nb = 2, 4, 8, 4, 16, 8, 3
+    P1 = B * nb + 1
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    kp = jax.random.normal(ks[0], (P1, bs, HKV, dh), jnp.float32)
+    vp = jax.random.normal(ks[1], (P1, bs, HKV, dh), jnp.float32)
+    q = jax.random.normal(ks[2], (B, W, HQ, dh), jnp.float32)
+    tables = jnp.asarray(np.arange(B * nb, dtype=np.int32).reshape(B, nb))
+    pos = jnp.asarray(np.array([13, 5], np.int32))
+    if name == "paged_decode":
+        return ops.paged_decode_attention(q[:, :1], kp, vp, tables, pos,
+                                          mesh=mesh)
+    if name == "paged_prefill":
+        return ops.paged_prefill_attention(q, kp, vp, tables, pos, mesh=mesh)
+    if name == "paged_prefill_int8":
+        amax = lambda p: jnp.max(jnp.abs(p), axis=(1, 3))
+        s_k = kv_quant.block_scales(amax(kp), jnp.int8)
+        s_v = kv_quant.block_scales(amax(vp), jnp.int8)
+        kq = kv_quant.quantize(kp, s_k[:, None, :, None], jnp.int8)
+        vq = kv_quant.quantize(vp, s_v[:, None, :, None], jnp.int8)
+        return ops.paged_prefill_attention(q, kq, vq, tables, pos, s_k, s_v,
+                                           mesh=mesh)
+    if name == "slot_decode":
+        T = nb * bs
+        ck = kp[:B * nb].reshape(B, T, HKV, dh)
+        cv = vp[:B * nb].reshape(B, T, HKV, dh)
+        slot_pos = jnp.tile(jnp.arange(T, dtype=jnp.int32)[None], (B, 1))
+        return ops.slot_decode_attention(q[:, :1], ck, cv, slot_pos, pos,
+                                         block_t=8, mesh=mesh)
+    if name == "flash":
+        k = jax.random.normal(ks[3], (B, W, HKV, dh), jnp.float32)
+        return ops.flash_attention(q, k, k, block_q=8, block_k=8, mesh=mesh)
+    assert name == "rmsnorm"
+    return ops.rmsnorm(q.reshape(B * W, HQ * dh),
+                       jnp.ones((HQ * dh,), jnp.float32), mesh=mesh)
+
+
+@needs_devices
+@pytest.mark.parametrize("name", ["paged_decode", "paged_prefill",
+                                  "paged_prefill_int8", "slot_decode",
+                                  "flash", "rmsnorm"])
+def test_kernel_per_shard_matches_single_device(name):
+    want = _kernel_call(name, None)
+    got = _kernel_call(name, make_host_mesh(1, 2))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-6)
+
+
+@needs_devices
+@pytest.mark.parametrize("other", ["ref", "mesh"])
+def test_teacher_forced_pallas_path(other):
+    """The paged, chunked serving passes with the Pallas kernels give the
+    reference path's logits, and the same logits on a 1x2 mesh — the CPU
+    mirror of chip_smoke.py's two comparisons."""
+    from repro.launch.serve import teacher_forced_logits
+    params = init_params(jax.random.PRNGKey(0), KV4)
+    rng = np.random.default_rng(5)
+    prompt = rng.integers(0, KV4.vocab_size, 20, dtype=np.int32)
+    forced = rng.integers(0, KV4.vocab_size, 6, dtype=np.int32)
+    kw = dict(block_size=8, chunk=16)
+    got = teacher_forced_logits(KV4, PALLAS_OPTS, params, prompt, forced,
+                                **kw)
+    want = (teacher_forced_logits(KV4, REF_OPTS, params, prompt, forced,
+                                  **kw) if other == "ref" else
+            teacher_forced_logits(KV4, PALLAS_OPTS, params, prompt, forced,
+                                  mesh=make_host_mesh(1, 2), **kw))
+    assert got.shape == (1 + len(forced), KV4.vocab_size)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)

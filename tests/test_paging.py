@@ -386,8 +386,8 @@ def test_paged_decode_kernel_matches_gathered_ref():
     valid = np.zeros((B, nb * bs), bool)
     valid[0, :13] = True                       # mid-block boundary
     valid[1, :1] = True                        # freshly admitted
-    out = paged_decode_attention(q, kp, vp, tables, jnp.asarray(valid),
-                                 interpret=True)
+    pos = jnp.asarray(valid.sum(axis=1) - 1, jnp.int32)   # last live lane
+    out = paged_decode_attention(q, kp, vp, tables, pos, interpret=True)
 
     kg = np.asarray(kp)[np.asarray(tables)].reshape(B, nb * bs, HKV, dh)
     vg = np.asarray(vp)[np.asarray(tables)].reshape(B, nb * bs, HKV, dh)

@@ -151,16 +151,8 @@ def test_hlo_bytes_calibration_band_vs_xla_loop_free():
     b = jnp.ones((512, 256))
     c = jax.jit(f).lower(a, b).compile()
     st = hlo_analysis.analyze(c.as_text())
-    ca = c.cost_analysis()
-    old_jax = isinstance(ca, (list, tuple))   # jax < 0.5 returns [dict]
-    if old_jax:
-        ca = ca[0]
-    xla = float(ca["bytes accessed"])
-    # older XLA cost models also count fusion-internal operand reads, so the
-    # band is wider on the low side than the [0.5x, 1.0x] the docstring
-    # derives for current XLA
-    lo = 0.25 if old_jax else 0.4
-    assert lo <= st.hbm_bytes / xla <= 1.1, (st.hbm_bytes, xla)
+    xla = float(c.cost_analysis()["bytes accessed"])
+    assert 0.4 <= st.hbm_bytes / xla <= 1.1, (st.hbm_bytes, xla)
 
 
 def test_hlo_loop_multiplier():

@@ -6,7 +6,13 @@ event schema and span state machine, and prints:
 
   * per program kind: steps, total host wall-clock, and the split across
     the pack / dispatch / device / host phases (the table bench_serving's
-    step-phase rows are derived from);
+    step-phase rows are derived from): pack is the host planning before
+    the program call (``engine.plan``), dispatch the call itself with its
+    table uploads (``engine.dispatch``), device the host's blocking waits
+    on results (a non-``ret_async`` commit's sync, ``engine.finalize``),
+    host the rest of the step (``engine.overlap``, ``engine.harvest``);
+  * the host spans (``engine.tick`` and the phases inside it): count and
+    seconds per span name;
   * request lifecycle stats from the spans: completed count, p50/p99 TTFT
     and latency;
   * event-type counts, so a glance shows which subsystems fired (swaps,
@@ -58,6 +64,16 @@ def summarize(path: str) -> None:
             print(f"phase shares: {shares}")
     else:
         print("no engine_step events")
+
+    spans = [e for e in events if e["type"] == "host_span"]
+    if spans:
+        per = {}
+        for e in spans:
+            n, t = per.get(e["args"]["name"], (0, 0.0))
+            per[e["args"]["name"]] = (n + 1, t + e.get("dur", 0.0))
+        print(f"{'host span':<18}{'count':>7}{'total_s':>10}")
+        for name, (n, t) in sorted(per.items(), key=lambda kv: -kv[1][1]):
+            print(f"{name:<18}{n:>7}{t:>10.4f}")
 
     lat = span_latencies(events)
     done = [d for d in lat.values() if "latency_s" in d]

@@ -197,21 +197,24 @@ class SamplingConfig:
 
 
 def make_sampler(sampling: Optional[SamplingConfig]) -> Callable:
-    """(logits (B,V), keys (B,2) uint32) -> (tokens (B,) int32, new keys)."""
+    """(logits (B,V), keys (B,2) uint32) -> (tokens (B,) int32, new keys).
+    The ops carry the ``sample`` scope."""
     if sampling is None or sampling.temperature <= 0.0:
         def greedy(logits, keys):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), keys
+            with jax.named_scope("sample"):
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32), keys
         return greedy
 
     def sample(logits, keys):
-        splits = jax.vmap(lambda k: jax.random.split(k, 2))(keys)   # (B,2,2)
-        new_keys, subs = splits[:, 0], splits[:, 1]
-        l = logits.astype(jnp.float32) / sampling.temperature
-        if sampling.top_k > 0:
-            kth = lax.top_k(l, sampling.top_k)[0][..., -1:]
-            l = jnp.where(l >= kth, l, -jnp.inf)
-        toks = jax.vmap(jax.random.categorical)(subs, l)
-        return toks.astype(jnp.int32), new_keys
+        with jax.named_scope("sample"):
+            splits = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+            new_keys, subs = splits[:, 0], splits[:, 1]         # (B,2) each
+            l = logits.astype(jnp.float32) / sampling.temperature
+            if sampling.top_k > 0:
+                kth = lax.top_k(l, sampling.top_k)[0][..., -1:]
+                l = jnp.where(l >= kth, l, -jnp.inf)
+            toks = jax.vmap(jax.random.categorical)(subs, l)
+            return toks.astype(jnp.int32), new_keys
     return sample
 
 
@@ -311,8 +314,10 @@ def _serving_decode_fn(one: Callable, linkage: LinkageConfig) -> Callable:
                 cache, toks, ks = carry
                 cache, nxt, ks = one(params, cache, toks, ks)
                 return (cache, nxt, ks), nxt
-            (cache, _, keys), seq = lax.scan(body, (cache, tokens, keys),
-                                             None, length=linkage.decode_steps)
+            with jax.named_scope("decode_microsteps"):
+                (cache, _, keys), seq = lax.scan(
+                    body, (cache, tokens, keys), None,
+                    length=linkage.decode_steps)
             return cache, seq.swapaxes(0, 1), keys   # (n_slots, K)
         return many
 
@@ -362,8 +367,11 @@ def build_slot_decode_step(cfg: ArchConfig, opts: ModelOptions,
             with jax.disable_jit():
                 return fn(params, cache, tokens, keys)
         return eager
-    return jax.jit(fn, **_serve_jit_kwargs(linkage, mesh, param_sharding,
-                                           cache_sharding))
+
+    def decode_program(params, cache, tokens, keys):
+        return fn(params, cache, tokens, keys)
+    return jax.jit(decode_program, **_serve_jit_kwargs(
+        linkage, mesh, param_sharding, cache_sharding))
 
 
 def make_paged_decode_fn(cfg: ArchConfig, opts: ModelOptions,
@@ -384,10 +392,10 @@ def make_paged_decode_fn(cfg: ArchConfig, opts: ModelOptions,
             return cache, nxt, keys
         return one
 
-    def fn(params, cache, tokens, keys, tables):
+    def decode_program(params, cache, tokens, keys, tables):
         return _serving_decode_fn(one_with_tables(tables), linkage)(
             params, cache, tokens, keys)
-    return fn
+    return decode_program
 
 
 def build_paged_decode_step(cfg: ArchConfig, opts: ModelOptions,
@@ -399,10 +407,12 @@ def build_paged_decode_step(cfg: ArchConfig, opts: ModelOptions,
     """(params, paged_cache, tokens (B,), keys (B,2), tables (B, nb)) ->
     (paged_cache, tokens (B, K), keys).
 
-    With ``mesh`` the physical block pools are per-shard resident (KV heads
-    over ``"model"``) while the block table stays one replicated *logical*
-    map — each shard resolves the same logical->physical translation against
-    its own slice of every block (``ArchSharding.serve_paged_cache_specs``).
+    The program is named ``decode_program`` (``jit_decode_program`` in a
+    profiler trace). With ``mesh`` the physical block pools are per-shard
+    resident (KV heads over ``"model"``) while the block table stays one
+    replicated *logical* map — each shard resolves the same
+    logical->physical translation against its own slice of every block
+    (``ArchSharding.serve_paged_cache_specs``).
     """
     linkage.validate()
     fn = make_paged_decode_fn(cfg, opts, linkage, max_len, sampling)
@@ -580,6 +590,10 @@ def build_serve_step(cfg: ArchConfig, opts: ModelOptions,
     paged adds trailing ``tables (B,nb)`` (chunk pass) and ``scan_tables``
     (decode scan: mid-prefill/empty rows redirected wholesale to trash).
 
+    The program is named ``serve_step``; its two halves carry the
+    ``chunk_pass`` and ``decode_microsteps`` scopes, so a profiler trace
+    tells them apart by op.
+
     W (the compiled chunk width) is implicit in the traced shapes — the
     engine pads every step to one fixed width, so this program jits a
     single shape where the two-phase engine compiled one prefill per
@@ -600,18 +614,20 @@ def build_serve_step(cfg: ArchConfig, opts: ModelOptions,
     if kv_kind not in ("slotted", "paged"):
         raise ValueError(f"unknown kv_kind {kv_kind!r}")
 
-    def fn(params, cache, chunk_toks, clen, start, reset, emit0, dec_tok,
-           dec_mask, keys, *tabs):
-        if paged:
-            tables, scan_tables = tabs
-            logits, cache = model_serve_chunk_paged(
-                params, cache, chunk_toks, tables, start, clen, cfg, opts,
-                max_len)
-        else:
-            logits, cache = model_serve_chunk(
-                params, cache, chunk_toks, start, clen, reset, cfg, opts)
-        t0, keys_c = sampler(logits, keys)
-        keys = jnp.where(emit0[:, None], keys_c, keys)
+    def serve_step(params, cache, chunk_toks, clen, start, reset, emit0,
+                   dec_tok, dec_mask, keys, *tabs):
+        with jax.named_scope("chunk_pass"):
+            if paged:
+                tables, scan_tables = tabs
+                logits, cache = model_serve_chunk_paged(
+                    params, cache, chunk_toks, tables, start, clen, cfg,
+                    opts, max_len)
+            else:
+                logits, cache = model_serve_chunk(
+                    params, cache, chunk_toks, start, clen, reset, cfg,
+                    opts)
+            t0, keys_c = sampler(logits, keys)
+            keys = jnp.where(emit0[:, None], keys_c, keys)
 
         def body(carry, _):
             c, toks, ks = carry
@@ -638,8 +654,9 @@ def build_serve_step(cfg: ArchConfig, opts: ModelOptions,
             ks = jnp.where(dec_mask[:, None], ks2, ks)
             return (c, nxt, ks), nxt
 
-        (cache, _, keys), seq = lax.scan(body, (cache, dec_tok, keys), None,
-                                         length=K)
+        with jax.named_scope("decode_microsteps"):
+            (cache, _, keys), seq = lax.scan(body, (cache, dec_tok, keys),
+                                             None, length=K)
         return cache, t0, seq.swapaxes(0, 1), keys
 
     if linkage.level == L0_EAGER:
@@ -648,7 +665,7 @@ def build_serve_step(cfg: ArchConfig, opts: ModelOptions,
 
         def eager(*args):
             with jax.disable_jit():
-                return fn(*args)
+                return serve_step(*args)
         return eager
 
     kwargs: Dict[str, Any] = {}
@@ -661,7 +678,7 @@ def build_serve_step(cfg: ArchConfig, opts: ModelOptions,
             NamedSharding(mesh, s) for s in operand_specs)
         repl = NamedSharding(mesh, P())
         kwargs["out_shardings"] = (cache_sharding, repl, repl, repl)
-    return jax.jit(fn, **kwargs)
+    return jax.jit(serve_step, **kwargs)
 
 
 def build_verify_step(cfg: ArchConfig, opts: ModelOptions,
@@ -709,7 +726,7 @@ def build_verify_step(cfg: ArchConfig, opts: ModelOptions,
     if kv_kind not in ("slotted", "paged"):
         raise ValueError(f"unknown kv_kind {kv_kind!r}")
 
-    def fn(params, cache, toks, clen, start, vmask, keys, *tabs):
+    def verify_step(params, cache, toks, clen, start, vmask, keys, *tabs):
         if paged:
             (tables,) = tabs
             logits, cache = model_serve_verify_paged(
@@ -758,7 +775,7 @@ def build_verify_step(cfg: ArchConfig, opts: ModelOptions,
 
         def eager(*args):
             with jax.disable_jit():
-                return fn(*args)
+                return verify_step(*args)
         return eager
 
     kwargs: Dict[str, Any] = {}
@@ -771,7 +788,7 @@ def build_verify_step(cfg: ArchConfig, opts: ModelOptions,
             NamedSharding(mesh, s) for s in operand_specs)
         repl = NamedSharding(mesh, P())
         kwargs["out_shardings"] = (cache_sharding, repl, repl, repl)
-    return jax.jit(fn, **kwargs)
+    return jax.jit(verify_step, **kwargs)
 
 
 def build_prefill_fn(cfg: ArchConfig, opts: ModelOptions, max_len: int, *,

@@ -126,12 +126,13 @@ _rmsnorm_bf16_grad.defvjp(_rmsnorm_bf16_fwd, _rmsnorm_bf16_bwd)
 
 
 def rmsnorm(params: Params, x: jax.Array, eps: float, opts: ModelOptions) -> jax.Array:
-    if opts.fused_norm:
-        from repro.kernels import ops as kops
-        return kops.rmsnorm(x, params["scale"], eps=eps, mesh=opts.mesh)
-    if opts.norm_bf16_grad:
-        return _rmsnorm_bf16_grad(params["scale"], x, eps)
-    return _rmsnorm_raw(params["scale"], x, eps)
+    with jax.named_scope("norm"):
+        if opts.fused_norm:
+            from repro.kernels import ops as kops
+            return kops.rmsnorm(x, params["scale"], eps=eps, mesh=opts.mesh)
+        if opts.norm_bf16_grad:
+            return _rmsnorm_bf16_grad(params["scale"], x, eps)
+        return _rmsnorm_raw(params["scale"], x, eps)
 
 
 # ---------------------------------------------------------------------------
@@ -370,12 +371,13 @@ def attention_decode(params: Params, x: jax.Array, cache: Params,
     k = rope(k, posv, cfg.rope_theta)
     T = cache["k"].shape[1]
     slot = pos % T
-    ck = lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                  (0, slot, 0, 0))
-    cv = lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                  (0, slot, 0, 0))
-    slot_pos = lax.dynamic_update_slice(cache["slot_pos"],
-                                        jnp.full((1,), pos, jnp.int32), (slot,))
+    with jax.named_scope("kv_write"):
+        ck = lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
+                                      (0, slot, 0, 0))
+        cv = lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
+                                      (0, slot, 0, 0))
+        slot_pos = lax.dynamic_update_slice(
+            cache["slot_pos"], jnp.full((1,), pos, jnp.int32), (slot,))
     window = cfg.sliding_window if spec.mixer == SWA else 0
 
     if opts.attn_impl == "pallas":
@@ -434,9 +436,10 @@ def attention_decode_slots(params: Params, x: jax.Array, cache: Params,
     slot = pos % T                                      # (B,) write index
     row_upd = lambda c, u, s: lax.dynamic_update_slice(
         c, u, (s,) + (0,) * (c.ndim - 1))
-    ck = jax.vmap(row_upd)(cache["k"], k.astype(cache["k"].dtype), slot)
-    cv = jax.vmap(row_upd)(cache["v"], v.astype(cache["v"].dtype), slot)
-    slot_pos = jax.vmap(row_upd)(cache["slot_pos"], pos[:, None], slot)
+    with jax.named_scope("kv_write"):
+        ck = jax.vmap(row_upd)(cache["k"], k.astype(cache["k"].dtype), slot)
+        cv = jax.vmap(row_upd)(cache["v"], v.astype(cache["v"].dtype), slot)
+        slot_pos = jax.vmap(row_upd)(cache["slot_pos"], pos[:, None], slot)
     window = cfg.sliding_window if spec.mixer == SWA else 0
 
     if opts.attn_impl == "pallas":
@@ -502,16 +505,19 @@ def attention_decode_paged(params: Params, x: jax.Array, cache: Params,
     blk = jnp.take_along_axis(tables, logical_blk[:, None], axis=1)[:, 0]
     off = pos % bs
     ks = vs = None
-    if "ks" in cache:
-        # quantized pool: block-level requantize-on-write (see kv_quant)
-        from repro.kernels import kv_quant
-        kp, ks = kv_quant.quant_insert(cache["kp"], cache["ks"], blk, off,
-                                       k[:, 0])
-        vp, vs = kv_quant.quant_insert(cache["vp"], cache["vs"], blk, off,
-                                       v[:, 0])
-    else:
-        kp = cache["kp"].at[blk, off].set(k[:, 0].astype(cache["kp"].dtype))
-        vp = cache["vp"].at[blk, off].set(v[:, 0].astype(cache["vp"].dtype))
+    with jax.named_scope("kv_write"):
+        if "ks" in cache:
+            # quantized pool: block-level requantize-on-write (see kv_quant)
+            from repro.kernels import kv_quant
+            kp, ks = kv_quant.quant_insert(cache["kp"], cache["ks"], blk,
+                                           off, k[:, 0])
+            vp, vs = kv_quant.quant_insert(cache["vp"], cache["vs"], blk,
+                                           off, v[:, 0])
+        else:
+            kp = cache["kp"].at[blk, off].set(
+                k[:, 0].astype(cache["kp"].dtype))
+            vp = cache["vp"].at[blk, off].set(
+                v[:, 0].astype(cache["vp"].dtype))
 
     if opts.attn_impl == "pallas":
         from repro.kernels import ops as kops
@@ -586,9 +592,12 @@ def attention_serve_chunk(params: Params, x: jax.Array, cache: Params,
         return c.at[ix].set(jnp.where(m.reshape((-1,) + (1,) * (u.ndim - 1)),
                                       u, old))
 
-    ck = jax.vmap(row_write)(cache["k"], k.astype(cache["k"].dtype), idx, real)
-    cv = jax.vmap(row_write)(cache["v"], v.astype(cache["v"].dtype), idx, real)
-    slot_pos = jax.vmap(row_write)(cache["slot_pos"], q_pos, idx, real)
+    with jax.named_scope("kv_write"):
+        ck = jax.vmap(row_write)(cache["k"], k.astype(cache["k"].dtype), idx,
+                                 real)
+        cv = jax.vmap(row_write)(cache["v"], v.astype(cache["v"].dtype), idx,
+                                 real)
+        slot_pos = jax.vmap(row_write)(cache["slot_pos"], q_pos, idx, real)
 
     # dense masked attention: (B, W) queries over the (B, T) row. The same
     # einsum/softmax structure as the slotted decode ref path, so a width-1
@@ -642,15 +651,19 @@ def attention_serve_chunk_paged(params: Params, x: jax.Array, cache: Params,
     blk = jnp.where(real, blk, trash)
     off = q_pos % bs
     ks = vs = None
-    if "ks" in cache:
-        # quantized pool: padding rows target the trash block, so their
-        # garbage writes requantize only the trash row (never validly read)
-        from repro.kernels import kv_quant
-        kp, ks = kv_quant.quant_insert(cache["kp"], cache["ks"], blk, off, k)
-        vp, vs = kv_quant.quant_insert(cache["vp"], cache["vs"], blk, off, v)
-    else:
-        kp = cache["kp"].at[blk, off].set(k.astype(cache["kp"].dtype))
-        vp = cache["vp"].at[blk, off].set(v.astype(cache["vp"].dtype))
+    with jax.named_scope("kv_write"):
+        if "ks" in cache:
+            # quantized pool: padding rows target the trash block, so their
+            # garbage writes requantize only the trash row (never validly
+            # read)
+            from repro.kernels import kv_quant
+            kp, ks = kv_quant.quant_insert(cache["kp"], cache["ks"], blk,
+                                           off, k)
+            vp, vs = kv_quant.quant_insert(cache["vp"], cache["vs"], blk,
+                                           off, v)
+        else:
+            kp = cache["kp"].at[blk, off].set(k.astype(cache["kp"].dtype))
+            vp = cache["vp"].at[blk, off].set(v.astype(cache["vp"].dtype))
 
     if opts.attn_impl == "pallas":
         from repro.kernels import ops as kops

@@ -83,28 +83,32 @@ def _shift_right(x: jax.Array) -> jax.Array:
 def apply_layer(p: Params, x: jax.Array, cfg: ArchConfig, spec: LayerSpec,
                 opts: ModelOptions, positions: jax.Array,
                 xctx: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
-    """Pre-norm residual layer. Returns (x, moe_aux)."""
+    """Pre-norm residual layer. Returns (x, moe_aux). Each half carries its
+    scope (``attn`` for the mixer, ``mlp``), residual add included; the
+    norms carry ``norm``."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps, opts)
-    if spec.mixer in (ATTN, SWA, XATTN):
-        mix = L.attention(p["mixer"], h, cfg, spec, opts, positions, xctx)
-    elif spec.mixer == MAMBA:
-        mix = L.mamba(p["mixer"], h, cfg, opts)
-    elif spec.mixer == RWKV:
-        mix = L.rwkv(p["mixer"], h, _shift_right(h), cfg, opts)
-    else:
-        raise ValueError(spec.mixer)
-    x = x + mix
+    with jax.named_scope("attn"):
+        if spec.mixer in (ATTN, SWA, XATTN):
+            mix = L.attention(p["mixer"], h, cfg, spec, opts, positions, xctx)
+        elif spec.mixer == MAMBA:
+            mix = L.mamba(p["mixer"], h, cfg, opts)
+        elif spec.mixer == RWKV:
+            mix = L.rwkv(p["mixer"], h, _shift_right(h), cfg, opts)
+        else:
+            raise ValueError(spec.mixer)
+        x = x + mix
     h = L.rmsnorm(p["norm2"], x, cfg.norm_eps, opts)
     aux = jnp.zeros((), jnp.float32)
-    if spec.mlp == DENSE:
-        out = L.mlp(p["mlp"], h)
-    elif spec.mlp == MOE:
-        out, aux = L.moe(p["mlp"], h, cfg, opts)
-    elif spec.mlp == RWKVMIX:
-        out = L.rwkv_mix(p["mlp"], h, _shift_right(h))
-    else:
-        raise ValueError(spec.mlp)
-    return x + out, aux
+    with jax.named_scope("mlp"):
+        if spec.mlp == DENSE:
+            out = L.mlp(p["mlp"], h)
+        elif spec.mlp == MOE:
+            out, aux = L.moe(p["mlp"], h, cfg, opts)
+        elif spec.mlp == RWKVMIX:
+            out = L.rwkv_mix(p["mlp"], h, _shift_right(h))
+        else:
+            raise ValueError(spec.mlp)
+        return x + out, aux
 
 
 # ---------------------------------------------------------------------------
@@ -130,14 +134,15 @@ def backbone(params: Params, h: jax.Array, cfg: ArchConfig, opts: ModelOptions,
         block_fn = jax.checkpoint(block_fn, prevent_cse=False)
 
     aux0 = jnp.zeros((), jnp.float32)
-    if opts.scan_blocks:
-        (h, aux), _ = lax.scan(block_fn, (h, aux0), params["blocks"])
-    else:
-        carry = (h, aux0)
-        for i in range(cfg.num_blocks):
-            blk = jax.tree.map(lambda a: a[i], params["blocks"])
-            carry, _ = block_fn(carry, blk)
-        h, aux = carry
+    with jax.named_scope("layer_scan"):
+        if opts.scan_blocks:
+            (h, aux), _ = lax.scan(block_fn, (h, aux0), params["blocks"])
+        else:
+            carry = (h, aux0)
+            for i in range(cfg.num_blocks):
+                blk = jax.tree.map(lambda a: a[i], params["blocks"])
+                carry, _ = block_fn(carry, blk)
+            h, aux = carry
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
     return h, aux
 
@@ -262,38 +267,63 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 # Decode step (one token) — scan over blocks threading the cache
 # ---------------------------------------------------------------------------
 
+def _block_loop(params: Params, cache, h, cfg: ArchConfig, opts: ModelOptions,
+                block_fn):
+    """Scan (or unroll) ``block_fn`` over the stacked blocks, threading the
+    cache: ``(h, new_cache)``. Under the ``layer_scan`` scope, so the scan's
+    own slicing and restacking of each layer's cache carry that scope and
+    no scope of the layer body."""
+    with jax.named_scope("layer_scan"):
+        if opts.scan_blocks:
+            return lax.scan(block_fn, h, (params["blocks"], cache))
+        outs = []
+        for i in range(cfg.num_blocks):
+            blk = jax.tree.map(lambda a: a[i], params["blocks"])
+            cb = jax.tree.map(lambda a: a[i], cache)
+            h, nc = block_fn(h, (blk, cb))
+            outs.append(nc)
+        return h, jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+
+
 def apply_layer_decode(p: Params, x: jax.Array, cache_l: Params,
                        cfg: ArchConfig, spec: LayerSpec, opts: ModelOptions,
                        slots: bool = False, paged_tables=None,
                        paged_max_len: int = 0) -> Tuple[jax.Array, Params]:
+    """One decode layer, scoped as ``apply_layer``."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps, opts)
-    if spec.mixer in (ATTN, SWA, XATTN):
-        if paged_tables is not None:
-            mix, cache_l = L.attention_decode_paged(
-                p["mixer"], h, cache_l, paged_tables, cfg, opts, paged_max_len)
+    with jax.named_scope("attn"):
+        if spec.mixer in (ATTN, SWA, XATTN):
+            if paged_tables is not None:
+                mix, cache_l = L.attention_decode_paged(
+                    p["mixer"], h, cache_l, paged_tables, cfg, opts,
+                    paged_max_len)
+            else:
+                attn_fn = (L.attention_decode_slots if slots
+                           else L.attention_decode)
+                mix, cache_l = attn_fn(p["mixer"], h, cache_l, cfg, spec,
+                                       opts)
+        elif spec.mixer == MAMBA:
+            mix, cache_l = L.mamba_decode(p["mixer"], h, cache_l, cfg)
+            cache_l = dict(cache_l, pos=cache_l["pos"] + 1)
+        elif spec.mixer == RWKV:
+            mix, cache_l = L.rwkv_decode(p["mixer"], h, cache_l, cfg)
+            cache_l = dict(cache_l, pos=cache_l["pos"] + 1)
         else:
-            attn_fn = L.attention_decode_slots if slots else L.attention_decode
-            mix, cache_l = attn_fn(p["mixer"], h, cache_l, cfg, spec, opts)
-    elif spec.mixer == MAMBA:
-        mix, cache_l = L.mamba_decode(p["mixer"], h, cache_l, cfg)
-        cache_l = dict(cache_l, pos=cache_l["pos"] + 1)
-    elif spec.mixer == RWKV:
-        mix, cache_l = L.rwkv_decode(p["mixer"], h, cache_l, cfg)
-        cache_l = dict(cache_l, pos=cache_l["pos"] + 1)
-    else:
-        raise ValueError(spec.mixer)
-    x = x + mix
+            raise ValueError(spec.mixer)
+        x = x + mix
     h = L.rmsnorm(p["norm2"], x, cfg.norm_eps, opts)
-    if spec.mlp == DENSE:
-        out = L.mlp(p["mlp"], h)
-    elif spec.mlp == MOE:
-        out, _ = L.moe(p["mlp"], h, cfg, opts)
-    elif spec.mlp == RWKVMIX:
-        out = L.rwkv_mix(p["mlp"], h, cache_l["shift_mlp"].astype(h.dtype))
-        cache_l = dict(cache_l, shift_mlp=h)
-    else:
-        raise ValueError(spec.mlp)
-    return x + out, cache_l
+    with jax.named_scope("mlp"):
+        if spec.mlp == DENSE:
+            out = L.mlp(p["mlp"], h)
+        elif spec.mlp == MOE:
+            out, _ = L.moe(p["mlp"], h, cfg, opts)
+        elif spec.mlp == RWKVMIX:
+            out = L.rwkv_mix(p["mlp"], h,
+                             cache_l["shift_mlp"].astype(h.dtype))
+            cache_l = dict(cache_l, shift_mlp=h)
+        else:
+            raise ValueError(spec.mlp)
+        return x + out, cache_l
 
 
 def decode_step(params: Params, cache, tokens: jax.Array, cfg: ArchConfig,
@@ -318,18 +348,10 @@ def decode_step(params: Params, cache, tokens: jax.Array, cfg: ArchConfig,
             new_c.append(cl)
         return x, tuple(new_c)
 
-    if opts.scan_blocks:
-        h, new_cache = lax.scan(block_fn, h, (params["blocks"], cache))
-    else:
-        outs = []
-        for i in range(cfg.num_blocks):
-            blk = jax.tree.map(lambda a: a[i], params["blocks"])
-            cb = jax.tree.map(lambda a: a[i], cache)
-            h, nc = block_fn(h, (blk, cb))
-            outs.append(nc)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
-    logits = unembed_logits(params, h, cfg)[:, 0]
+    h, new_cache = _block_loop(params, cache, h, cfg, opts, block_fn)
+    with jax.named_scope("head"):
+        h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
+        logits = unembed_logits(params, h, cfg)[:, 0]
     return logits, new_cache
 
 
@@ -375,18 +397,10 @@ def decode_step_paged(params: Params, cache, tokens: jax.Array,
             new_c.append(cl)
         return x, tuple(new_c)
 
-    if opts.scan_blocks:
-        h, new_cache = lax.scan(block_fn, h, (params["blocks"], cache))
-    else:
-        outs = []
-        for i in range(cfg.num_blocks):
-            blk = jax.tree.map(lambda a: a[i], params["blocks"])
-            cb = jax.tree.map(lambda a: a[i], cache)
-            h, nc = block_fn(h, (blk, cb))
-            outs.append(nc)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
-    logits = unembed_logits(params, h, cfg)[:, 0]
+    h, new_cache = _block_loop(params, cache, h, cfg, opts, block_fn)
+    with jax.named_scope("head"):
+        h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
+        logits = unembed_logits(params, h, cfg)[:, 0]
     return logits, new_cache
 
 
@@ -397,8 +411,8 @@ def decode_step_paged(params: Params, cache, tokens: jax.Array,
 def _serve_chunk_block(params: Params, cache, h, cfg: ArchConfig,
                        opts: ModelOptions, layer_fn):
     """Shared block loop for the serve chunk passes: scan (or unroll) the
-    stacked blocks threading the cache, finishing with final norm + per-row
-    last-real-position logits."""
+    stacked blocks threading the cache (the caller finishes with final norm
+    + logits)."""
     def block_fn(x, xs):
         block_params, cache_b = xs
         new_c = []
@@ -407,37 +421,33 @@ def _serve_chunk_block(params: Params, cache, h, cfg: ArchConfig,
             new_c.append(cl)
         return x, tuple(new_c)
 
-    if opts.scan_blocks:
-        h, new_cache = lax.scan(block_fn, h, (params["blocks"], cache))
-    else:
-        outs = []
-        for i in range(cfg.num_blocks):
-            blk = jax.tree.map(lambda a: a[i], params["blocks"])
-            cb = jax.tree.map(lambda a: a[i], cache)
-            h, nc = block_fn(h, (blk, cb))
-            outs.append(nc)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-    return h, new_cache
+    return _block_loop(params, cache, h, cfg, opts, block_fn)
 
 
-def _chunk_mlp(p: Params, x, cfg: ArchConfig, spec: LayerSpec,
+def _chunk_mlp(p: Params, x, mix, cfg: ArchConfig, spec: LayerSpec,
                opts: ModelOptions):
+    """The rest of a chunk layer after its attention: the residual add
+    (under ``attn``, as in ``apply_layer``), then the MLP half."""
+    with jax.named_scope("attn"):
+        x = x + mix
     h = L.rmsnorm(p["norm2"], x, cfg.norm_eps, opts)
-    if spec.mlp == MOE:
-        out, _ = L.moe(p["mlp"], h, cfg, opts)
-    else:
-        out = L.mlp(p["mlp"], h)
-    return x + out
+    with jax.named_scope("mlp"):
+        if spec.mlp == MOE:
+            out, _ = L.moe(p["mlp"], h, cfg, opts)
+        else:
+            out = L.mlp(p["mlp"], h)
+        return x + out
 
 
 def _chunk_logits(params: Params, h, clen, cfg: ArchConfig,
                   opts: ModelOptions):
     """Logits at each row's last real chunk position (clamped for rows with
     no chunk — their output is discarded by the caller's emit mask)."""
-    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
-    last = jnp.take_along_axis(
-        h, jnp.clip(clen - 1, 0, h.shape[1] - 1)[:, None, None], axis=1)
-    return unembed_logits(params, last, cfg)[:, 0]
+    with jax.named_scope("head"):
+        h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
+        last = jnp.take_along_axis(
+            h, jnp.clip(clen - 1, 0, h.shape[1] - 1)[:, None, None], axis=1)
+        return unembed_logits(params, last, cfg)[:, 0]
 
 
 def serve_chunk_step(params: Params, cache, tokens: jax.Array,
@@ -460,9 +470,10 @@ def serve_chunk_step(params: Params, cache, tokens: jax.Array,
 
     def layer_fn(spec, bp, x, cl):
         hh = L.rmsnorm(bp["norm1"], x, cfg.norm_eps, opts)
-        mix, cl = L.attention_serve_chunk(bp["mixer"], hh, cl, cfg, opts,
-                                          start, clen)
-        x = _chunk_mlp(bp, x + mix, cfg, spec, opts)
+        with jax.named_scope("attn"):
+            mix, cl = L.attention_serve_chunk(bp["mixer"], hh, cl, cfg,
+                                              opts, start, clen)
+        x = _chunk_mlp(bp, x, mix, cfg, spec, opts)
         return x, cl
 
     h, new_cache = _serve_chunk_block(params, cache, h, cfg, opts, layer_fn)
@@ -482,10 +493,11 @@ def serve_chunk_step_paged(params: Params, cache, tokens: jax.Array,
 
     def layer_fn(spec, bp, x, cl):
         hh = L.rmsnorm(bp["norm1"], x, cfg.norm_eps, opts)
-        mix, cl = L.attention_serve_chunk_paged(bp["mixer"], hh, cl, tables,
-                                                cfg, opts, start, clen,
-                                                max_len)
-        x = _chunk_mlp(bp, x + mix, cfg, spec, opts)
+        with jax.named_scope("attn"):
+            mix, cl = L.attention_serve_chunk_paged(
+                bp["mixer"], hh, cl, tables, cfg, opts, start, clen,
+                max_len)
+        x = _chunk_mlp(bp, x, mix, cfg, spec, opts)
         return x, cl
 
     h, new_cache = _serve_chunk_block(params, cache, h, cfg, opts, layer_fn)
@@ -500,8 +512,9 @@ def _verify_logits(params: Params, h, cfg: ArchConfig, opts: ModelOptions):
     """Logits at all W fed positions: (B, W, V). The verify pass needs the
     model's next-token distribution after each drafted prefix, not just the
     chunk's last position."""
-    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
-    return unembed_logits(params, h, cfg)
+    with jax.named_scope("head"):
+        h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
+        return unembed_logits(params, h, cfg)
 
 
 def serve_verify_step(params: Params, cache, tokens: jax.Array,
@@ -519,9 +532,10 @@ def serve_verify_step(params: Params, cache, tokens: jax.Array,
 
     def layer_fn(spec, bp, x, cl):
         hh = L.rmsnorm(bp["norm1"], x, cfg.norm_eps, opts)
-        mix, cl = L.attention_serve_chunk(bp["mixer"], hh, cl, cfg, opts,
-                                          start, clen)
-        x = _chunk_mlp(bp, x + mix, cfg, spec, opts)
+        with jax.named_scope("attn"):
+            mix, cl = L.attention_serve_chunk(bp["mixer"], hh, cl, cfg,
+                                              opts, start, clen)
+        x = _chunk_mlp(bp, x, mix, cfg, spec, opts)
         return x, cl
 
     h, new_cache = _serve_chunk_block(params, cache, h, cfg, opts, layer_fn)
@@ -540,10 +554,11 @@ def serve_verify_step_paged(params: Params, cache, tokens: jax.Array,
 
     def layer_fn(spec, bp, x, cl):
         hh = L.rmsnorm(bp["norm1"], x, cfg.norm_eps, opts)
-        mix, cl = L.attention_serve_chunk_paged(bp["mixer"], hh, cl, tables,
-                                                cfg, opts, start, clen,
-                                                max_len)
-        x = _chunk_mlp(bp, x + mix, cfg, spec, opts)
+        with jax.named_scope("attn"):
+            mix, cl = L.attention_serve_chunk_paged(
+                bp["mixer"], hh, cl, tables, cfg, opts, start, clen,
+                max_len)
+        x = _chunk_mlp(bp, x, mix, cfg, spec, opts)
         return x, cl
 
     h, new_cache = _serve_chunk_block(params, cache, h, cfg, opts, layer_fn)
@@ -592,16 +607,7 @@ def prefill(params: Params, tokens: jax.Array, cfg: ArchConfig,
             new_c.append(cl)
         return x, tuple(new_c)
 
-    if opts.scan_blocks:
-        h, new_cache = lax.scan(block_fn, h, (params["blocks"], cache))
-    else:
-        outs = []
-        for i in range(cfg.num_blocks):
-            blk = jax.tree.map(lambda a: a[i], params["blocks"])
-            cb = jax.tree.map(lambda a: a[i], cache)
-            h, nc = block_fn(h, (blk, cb))
-            outs.append(nc)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    h, new_cache = _block_loop(params, cache, h, cfg, opts, block_fn)
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
     if true_len is None:
         logits = unembed_logits(params, h[:, -1:], cfg)[:, 0]
@@ -661,16 +667,7 @@ def prefill_suffix(params: Params, tokens: jax.Array, prefix_kv: Tuple,
             new_kv.append(kv)
         return x, tuple(new_kv)
 
-    if opts.scan_blocks:
-        h, suffix_kv = lax.scan(block_fn, h, (params["blocks"], prefix_kv))
-    else:
-        outs = []
-        for i in range(cfg.num_blocks):
-            blk = jax.tree.map(lambda a: a[i], params["blocks"])
-            pre = jax.tree.map(lambda a: a[i], prefix_kv)
-            h, kv = block_fn(h, (blk, pre))
-            outs.append(kv)
-        suffix_kv = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    h, suffix_kv = _block_loop(params, prefix_kv, h, cfg, opts, block_fn)
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, opts)
     if true_len is None:
         logits = unembed_logits(params, h[:, -1:], cfg)[:, 0]
@@ -683,95 +680,102 @@ def prefill_suffix(params: Params, tokens: jax.Array, prefix_kv: Tuple,
 def _prefill_suffix_layer(p, x, pkv, cfg, spec, opts, q_pos, k_pos):
     """One plain-attention layer of the suffix prefill. Returns (x, {k, v})."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps, opts)
-    B, S, _ = x.shape
-    q, k, v = L._qkv(p["mixer"], h, cfg)
-    q = L.rope(q, q_pos, cfg.rope_theta)
-    k = L.rope(k, q_pos, cfg.rope_theta)
-    start = (0, q_pos[0], 0, 0)
-    k_full = lax.dynamic_update_slice(pkv["k"].astype(k.dtype), k, start)
-    v_full = lax.dynamic_update_slice(pkv["v"].astype(v.dtype), v, start)
-    kwargs = dict(causal=True, window=0, q_pos=q_pos, k_pos=k_pos)
-    if opts.attn_impl == "ref":
-        out = L._sdpa_ref(q, k_full, v_full, **kwargs)
-    else:
-        # rectangular q/kv: the blockwise form handles it; the Pallas prefill
-        # kernel assumes square q/kv, so "pallas" also lowers through here
-        out = L._sdpa_chunked(q, k_full, v_full, q_chunk=opts.q_chunk,
-                              kv_chunk=opts.kv_chunk, **kwargs)
-    x = x + out.reshape(B, S, -1) @ p["mixer"]["wo"].astype(x.dtype)
+    with jax.named_scope("attn"):
+        B, S, _ = x.shape
+        q, k, v = L._qkv(p["mixer"], h, cfg)
+        q = L.rope(q, q_pos, cfg.rope_theta)
+        k = L.rope(k, q_pos, cfg.rope_theta)
+        start = (0, q_pos[0], 0, 0)
+        with jax.named_scope("kv_write"):
+            k_full = lax.dynamic_update_slice(pkv["k"].astype(k.dtype), k,
+                                              start)
+            v_full = lax.dynamic_update_slice(pkv["v"].astype(v.dtype), v,
+                                              start)
+        kwargs = dict(causal=True, window=0, q_pos=q_pos, k_pos=k_pos)
+        if opts.attn_impl == "ref":
+            out = L._sdpa_ref(q, k_full, v_full, **kwargs)
+        else:
+            # rectangular q/kv: the blockwise form handles it; the Pallas prefill
+            # kernel assumes square q/kv, so "pallas" also lowers through here
+            out = L._sdpa_chunked(q, k_full, v_full, q_chunk=opts.q_chunk,
+                                  kv_chunk=opts.kv_chunk, **kwargs)
+        x = x + out.reshape(B, S, -1) @ p["mixer"]["wo"].astype(x.dtype)
     h2 = L.rmsnorm(p["norm2"], x, cfg.norm_eps, opts)
-    if spec.mlp == MOE:
-        out2, _ = L.moe(p["mlp"], h2, cfg, opts)
-    else:
-        out2 = L.mlp(p["mlp"], h2)
-    return x + out2, {"k": k, "v": v}
+    with jax.named_scope("mlp"):
+        if spec.mlp == MOE:
+            out2, _ = L.moe(p["mlp"], h2, cfg, opts)
+        else:
+            out2 = L.mlp(p["mlp"], h2)
+        return x + out2, {"k": k, "v": v}
 
 
 def _prefill_layer(p, x, cache_l, cfg, spec, opts, positions, xctx):
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps, opts)
     S = x.shape[1]
-    if spec.mixer in (ATTN, SWA, XATTN):
-        mix = L.attention(p["mixer"], h, cfg, spec, opts, positions, xctx)
-        # write K/V into cache (recompute projections; XLA CSEs with the above)
-        q, k, v = L._qkv(p["mixer"], h, cfg)
-        k = L.rope(k, positions, cfg.rope_theta)
-        T = cache_l["k"].shape[1]
-        if S >= T:
-            # decode assumes a circular layout (position p lives at slot p % T)
-            roll = S % T
-            cache_l = dict(cache_l,
-                           k=jnp.roll(k[:, S - T:], roll, axis=1).astype(cache_l["k"].dtype),
-                           v=jnp.roll(v[:, S - T:], roll, axis=1).astype(cache_l["v"].dtype),
-                           slot_pos=jnp.roll(positions[S - T:], roll),
+    with jax.named_scope("attn"):
+        if spec.mixer in (ATTN, SWA, XATTN):
+            mix = L.attention(p["mixer"], h, cfg, spec, opts, positions, xctx)
+            # write K/V into cache (recompute projections; XLA CSEs with the above)
+            q, k, v = L._qkv(p["mixer"], h, cfg)
+            k = L.rope(k, positions, cfg.rope_theta)
+            T = cache_l["k"].shape[1]
+            if S >= T:
+                # decode assumes a circular layout (position p lives at slot p % T)
+                roll = S % T
+                cache_l = dict(cache_l,
+                               k=jnp.roll(k[:, S - T:], roll, axis=1).astype(cache_l["k"].dtype),
+                               v=jnp.roll(v[:, S - T:], roll, axis=1).astype(cache_l["v"].dtype),
+                               slot_pos=jnp.roll(positions[S - T:], roll),
+                               pos=jnp.asarray(S, jnp.int32))
+            else:
+                ck = lax.dynamic_update_slice(cache_l["k"], k.astype(cache_l["k"].dtype),
+                                              (0, 0, 0, 0))
+                cv = lax.dynamic_update_slice(cache_l["v"], v.astype(cache_l["v"].dtype),
+                                              (0, 0, 0, 0))
+                sp = lax.dynamic_update_slice(cache_l["slot_pos"], positions, (0,))
+                cache_l = dict(cache_l, k=ck, v=cv, slot_pos=sp,
+                               pos=jnp.asarray(S, jnp.int32))
+            if spec.mixer == XATTN:
+                hkv, dh = cfg.n_kv_heads, cfg.head_dim
+                xk = (xctx @ p["mixer"]["xk"].astype(x.dtype)).reshape(x.shape[0], -1, hkv, dh)
+                xv = (xctx @ p["mixer"]["xv"].astype(x.dtype)).reshape(x.shape[0], -1, hkv, dh)
+                cache_l = dict(cache_l, xk=xk.astype(cache_l["xk"].dtype),
+                               xv=xv.astype(cache_l["xv"].dtype))
+        elif spec.mixer == MAMBA:
+            dt, A, Bv, Cv, xc, z, conv_state = L._mamba_gates(p["mixer"], h, cfg)
+            if opts.scan_impl == "ref":
+                y, h_last = L.mamba_scan_ref(xc, dt, A, Bv, Cv)
+            else:
+                y, h_last = L.mamba_scan_chunked(xc, dt, A, Bv, Cv, opts.scan_chunk)
+            y = (y + xc.astype(jnp.float32) * p["mixer"]["D"]).astype(x.dtype)
+            y = y * jax.nn.silu(z)
+            mix = y @ p["mixer"]["out_proj"].astype(x.dtype)
+            cache_l = dict(cache_l, conv=conv_state.astype(cache_l["conv"].dtype),
+                           ssm=h_last, pos=jnp.asarray(S, jnp.int32))
+        elif spec.mixer == RWKV:
+            shifted = jnp.pad(h, ((0, 0), (1, 0), (0, 0)))[:, :-1]
+            r, k, v, g, w = L._rwkv_gates(p["mixer"], h, shifted, cfg)
+            u = p["mixer"]["u"].astype(jnp.float32)
+            if opts.scan_impl == "ref":
+                y, s_last = L.rwkv_scan_ref(r, k, v, w, u)
+            else:
+                y, s_last = L.rwkv_scan_chunked(r, k, v, w, u, opts.scan_chunk)
+            mix = L._rwkv_out(p["mixer"], y, g, h, cfg)
+            cache_l = dict(cache_l, state=s_last, shift=h[:, -1:],
                            pos=jnp.asarray(S, jnp.int32))
         else:
-            ck = lax.dynamic_update_slice(cache_l["k"], k.astype(cache_l["k"].dtype),
-                                          (0, 0, 0, 0))
-            cv = lax.dynamic_update_slice(cache_l["v"], v.astype(cache_l["v"].dtype),
-                                          (0, 0, 0, 0))
-            sp = lax.dynamic_update_slice(cache_l["slot_pos"], positions, (0,))
-            cache_l = dict(cache_l, k=ck, v=cv, slot_pos=sp,
-                           pos=jnp.asarray(S, jnp.int32))
-        if spec.mixer == XATTN:
-            hkv, dh = cfg.n_kv_heads, cfg.head_dim
-            xk = (xctx @ p["mixer"]["xk"].astype(x.dtype)).reshape(x.shape[0], -1, hkv, dh)
-            xv = (xctx @ p["mixer"]["xv"].astype(x.dtype)).reshape(x.shape[0], -1, hkv, dh)
-            cache_l = dict(cache_l, xk=xk.astype(cache_l["xk"].dtype),
-                           xv=xv.astype(cache_l["xv"].dtype))
-    elif spec.mixer == MAMBA:
-        dt, A, Bv, Cv, xc, z, conv_state = L._mamba_gates(p["mixer"], h, cfg)
-        if opts.scan_impl == "ref":
-            y, h_last = L.mamba_scan_ref(xc, dt, A, Bv, Cv)
-        else:
-            y, h_last = L.mamba_scan_chunked(xc, dt, A, Bv, Cv, opts.scan_chunk)
-        y = (y + xc.astype(jnp.float32) * p["mixer"]["D"]).astype(x.dtype)
-        y = y * jax.nn.silu(z)
-        mix = y @ p["mixer"]["out_proj"].astype(x.dtype)
-        cache_l = dict(cache_l, conv=conv_state.astype(cache_l["conv"].dtype),
-                       ssm=h_last, pos=jnp.asarray(S, jnp.int32))
-    elif spec.mixer == RWKV:
-        shifted = jnp.pad(h, ((0, 0), (1, 0), (0, 0)))[:, :-1]
-        r, k, v, g, w = L._rwkv_gates(p["mixer"], h, shifted, cfg)
-        u = p["mixer"]["u"].astype(jnp.float32)
-        if opts.scan_impl == "ref":
-            y, s_last = L.rwkv_scan_ref(r, k, v, w, u)
-        else:
-            y, s_last = L.rwkv_scan_chunked(r, k, v, w, u, opts.scan_chunk)
-        mix = L._rwkv_out(p["mixer"], y, g, h, cfg)
-        cache_l = dict(cache_l, state=s_last, shift=h[:, -1:],
-                       pos=jnp.asarray(S, jnp.int32))
-    else:
-        raise ValueError(spec.mixer)
-    x = x + mix
+            raise ValueError(spec.mixer)
+        x = x + mix
     h2 = L.rmsnorm(p["norm2"], x, cfg.norm_eps, opts)
-    if spec.mlp == DENSE:
-        out = L.mlp(p["mlp"], h2)
-    elif spec.mlp == MOE:
-        out, _ = L.moe(p["mlp"], h2, cfg, opts)
-    elif spec.mlp == RWKVMIX:
-        shifted2 = jnp.pad(h2, ((0, 0), (1, 0), (0, 0)))[:, :-1]
-        out = L.rwkv_mix(p["mlp"], h2, shifted2)
-        cache_l = dict(cache_l, shift_mlp=h2[:, -1:])
-    else:
-        raise ValueError(spec.mlp)
-    return x + out, cache_l
+    with jax.named_scope("mlp"):
+        if spec.mlp == DENSE:
+            out = L.mlp(p["mlp"], h2)
+        elif spec.mlp == MOE:
+            out, _ = L.moe(p["mlp"], h2, cfg, opts)
+        elif spec.mlp == RWKVMIX:
+            shifted2 = jnp.pad(h2, ((0, 0), (1, 0), (0, 0)))[:, :-1]
+            out = L.rwkv_mix(p["mlp"], h2, shifted2)
+            cache_l = dict(cache_l, shift_mlp=h2[:, -1:])
+        else:
+            raise ValueError(spec.mlp)
+        return x + out, cache_l

@@ -228,6 +228,14 @@ class ServeEngine:
         self.handoffs_out = 0        # fleet: chains handed to a decode cell
         self.handoffs_in = 0         # fleet: chains adopted from a prefill
                                      # cell (swap-in landed in this pool)
+        # token positions the dispatched programs computed (slots x (chunk
+        # width + K) a serve step, slots x K a decode program, slots x spec
+        # width a verify program) and those of them that did useful work
+        # (prompt positions absorbed, decode microsteps making a kept token,
+        # verify positions emitting one)
+        self.positions_computed = 0
+        self.positions_useful = 0
+        self._waited = 0.0           # this step's blocking waits on results
 
     def _bucket(self, n: int) -> int:
         """Power-of-two admission bucket (owned by the scheduler module —
@@ -389,9 +397,8 @@ class ServeEngine:
     def _overlap_host_work(self) -> None:
         """Host-side work pipelined under the just-dispatched device step:
         drain the swap stream and stage the resume-head prefetch. Runs
-        between dispatch and the blocking host sync, so its cost lands
-        inside the step's device phase instead of pack/host — the overlap
-        PR 7's trace phase breakdown makes visible."""
+        between dispatch and the commit, inside the ``engine.overlap`` span;
+        the step event counts it as host time."""
         tel = self.tel
         t = tel.now()
         if self._drain_swaps():
@@ -427,15 +434,20 @@ class ServeEngine:
             if spec is not None:
                 return ("done", spec)
         tel = self.tel
-        t0 = tel.now()
-        self._reserve_all()
-        t1 = tel.now()
-        toks = self.kv.decode(self._next)
-        self._next = toks[:, -1]
-        self.programs_run += 1
-        t2 = tel.now()
-        self._overlap_host_work()      # under the dispatched device step
-        return ("decode", (toks, t0, t1, t2))
+        K = self.tokens_per_program
+        with tel.span("engine.plan") as plan:
+            self._reserve_all()
+        self._count_positions(
+            sum(min(K, st.remaining) for st in self.sched.active.values()),
+            self.n_slots * K)
+        with tel.span("engine.dispatch", kind="decode",
+                      program=self.programs_run) as disp:
+            toks = self.kv.decode(self._next)
+            self._next = toks[:, -1]
+            self.programs_run += 1
+        with tel.span("engine.overlap"):
+            self._overlap_host_work()  # under the dispatched device step
+        return ("decode", (toks, plan, disp))
 
     def _step_end(self, pending, now_fn: Callable[[], float]
                   ) -> List[Completion]:
@@ -448,20 +460,37 @@ class ServeEngine:
         if tag == "chunk":
             return self._chunk_end(data, now_fn)
         tel = self.tel
-        toks, t0, t1, t2 = data
-        toks_host = None
-        if not self.linkage.ret_async:
-            toks_host = np.asarray(toks)            # "iret": sync every program
-        t3 = tel.now()
-        slots = sorted(self.sched.active)
-        tel.decode_microsteps(len(slots), self.tokens_per_program, t1)
-        finished = self._harvest_decode(slots, toks, toks_host, now_fn)
-        tel.step("decode", self.programs_run, t0, t1 - t0, t2 - t1, t3 - t2,
-                 tel.now() - t3, queued=self.sched.n_queued,
-                 active=len(self.sched.active),
-                 swapped=len(self.sched.swapped),
-                 program=self._labels["decode"])
+        toks, plan, disp = data
+        with tel.span("engine.harvest") as harvest:
+            self._waited = 0.0
+            toks_host = None
+            if not self.linkage.ret_async:
+                t = tel.now()
+                toks_host = np.asarray(toks)        # "iret": sync every program
+                self._waited += tel.now() - t
+            slots = sorted(self.sched.active)
+            tel.decode_microsteps(len(slots), self.tokens_per_program,
+                                  disp.t0)
+            finished = self._harvest_decode(slots, toks, toks_host, now_fn)
+        self._step_event("decode", plan, disp, harvest)
         return finished
+
+    def _count_positions(self, useful: int, computed: int) -> None:
+        self.positions_useful += useful
+        self.positions_computed += computed
+
+    def _step_event(self, kind: str, plan, disp, harvest) -> None:
+        """The ``engine_step`` event of one program, from its spans: pack is
+        everything before the dispatch, device the blocking waits on
+        results (a non-``ret_async`` commit's sync, ``_finalize``'s), host
+        the rest up to the end of the harvest (the overlap work included)."""
+        tel = self.tel
+        tel.step(kind, self.programs_run, plan.t0, disp.t0 - plan.t0,
+                 disp.t1 - disp.t0, self._waited,
+                 harvest.t1 - disp.t1 - self._waited,
+                 queued=self.sched.n_queued, active=len(self.sched.active),
+                 swapped=len(self.sched.swapped),
+                 program=self._labels[kind])
 
     # -- speculative decode: draft-and-verify -------------------------------
 
@@ -501,40 +530,54 @@ class ServeEngine:
             return None                   # a slot with no committed token
                                           # yet cannot feed a verify row
         tel = self.tel
-        t0 = tel.now()
-        any_draft = False
-        for s in order:
-            st = self.sched.active[s]
-            st.pending_drafts = self.proposer.propose(st)
-            any_draft = any_draft or st.pending_drafts.size > 0
-        if not any_draft:
+        with tel.span("engine.plan") as plan:
+            any_draft = False
             for s in order:
-                self.sched.active[s].pending_drafts = None
-            return None
-        self._reserve_spec()
-        order = sorted(self.sched.active)   # preemption may have evicted
-        B, W = self.n_slots, self.spec_width
-        toks = np.zeros((B, W), np.int32)
-        clen = np.zeros(B, np.int32)
-        start = np.zeros(B, np.int32)
-        vmask = np.zeros(B, bool)
-        nxt_host = np.asarray(self._next)
-        for s in order:
-            st = self.sched.active[s]
-            m = int(st.pending_drafts.size)
-            toks[s, 0] = nxt_host[s]
-            toks[s, 1:1 + m] = st.pending_drafts
-            clen[s] = 1 + m
-            start[s] = st.prompt_len + st.produced - 1   # next write position
-            vmask[s] = True
+                st = self.sched.active[s]
+                st.pending_drafts = self.proposer.propose(st)
+                any_draft = any_draft or st.pending_drafts.size > 0
+            if not any_draft:
+                for s in order:
+                    self.sched.active[s].pending_drafts = None
+                return None
+            self._reserve_spec()
+            order = sorted(self.sched.active)   # preemption may have evicted
+            B, W = self.n_slots, self.spec_width
+            toks = np.zeros((B, W), np.int32)
+            clen = np.zeros(B, np.int32)
+            start = np.zeros(B, np.int32)
+            vmask = np.zeros(B, bool)
+            nxt_host = np.asarray(self._next)
+            for s in order:
+                st = self.sched.active[s]
+                m = int(st.pending_drafts.size)
+                toks[s, 0] = nxt_host[s]
+                toks[s, 1:1 + m] = st.pending_drafts
+                clen[s] = 1 + m
+                start[s] = st.prompt_len + st.produced - 1   # next write pos
+                vmask[s] = True
 
-        t1 = tel.now()
-        out, n_emit = self.kv.verify_step(toks, clen, start, vmask)
-        self.programs_run += 1
-        self.spec_steps += 1
-        t2 = tel.now()
+        with tel.span("engine.dispatch", kind="verify",
+                      program=self.programs_run) as disp:
+            out, n_emit = self.kv.verify_step(toks, clen, start, vmask)
+            self.programs_run += 1
+            self.spec_steps += 1
+        with tel.span("engine.harvest") as harvest:
+            finished = self._harvest_spec(order, start, nxt_host, out,
+                                          n_emit, now_fn)
+        self._count_positions(int(np.asarray(n_emit).sum()), B * W)
+        self._step_event("verify", plan, disp, harvest)
+        return finished
+
+    def _harvest_spec(self, order, start, nxt_host, out, n_emit,
+                      now_fn: Callable[[], float]) -> List[Completion]:
+        """Sync a verify program's accept counts and tokens, commit each
+        row's accepted prefix, and finalize finished rows."""
+        tel = self.tel
+        self._waited = 0.0
+        t = tel.now()
         out_host, n_host = np.asarray(out), np.asarray(n_emit)
-        t3 = tel.now()
+        self._waited += tel.now() - t
         nxt = nxt_host.copy()
         for s in order:
             nxt[s] = out_host[s, int(n_host[s]) - 1]
@@ -568,11 +611,6 @@ class ServeEngine:
             self.kv.rollback(s, int(start[s]) + a)
             if st.produced >= st.req.max_new_tokens or st.eos_seen:
                 finished.append(self._finalize(s, now_fn))
-        tel.step("verify", self.programs_run, t0, t1 - t0, t2 - t1, t3 - t2,
-                 tel.now() - t3, queued=self.sched.n_queued,
-                 active=len(self.sched.active),
-                 swapped=len(self.sched.swapped),
-                 program=self._labels["verify"])
         return finished
 
     def _harvest_decode(self, slots, toks, toks_host,
@@ -694,95 +732,102 @@ class ServeEngine:
         if not any(self.sched.active[s].prefilling for s in self.sched.active):
             return self._step_begin(now_fn)
         tel = self.tel
-        w0 = tel.now()
-        B, W = self.n_slots, self.chunk_width
-        dec, pre, grants = self._plan_chunks()
-        tel.pack(self.chunk_budget, self.tokens_per_program * len(dec),
-                 int(sum(grants)), w0)
-        toks = np.zeros((B, W), np.int32)
-        clen = np.zeros(B, np.int32)
-        start = np.zeros(B, np.int32)
-        reset = np.zeros(B, bool)
-        emit0 = np.zeros(B, bool)
-        dec_mask = np.zeros(B, bool)
-        for s in dec:
-            st = self.sched.active[s]
-            start[s] = st.prompt_len + st.produced - 1   # next write position
-            dec_mask[s] = True
-        for s, g in zip(pre, grants):
-            st = self.sched.active[s]
-            start[s] = st.prefill_pos
-            clen[s] = g
-            toks[s, :g] = st.req.prompt[st.prefill_pos:st.prefill_pos + g]
-            if g:
-                reset[s] = st.fresh
-                st.fresh = False
-                emit0[s] = st.prefill_pos + g == st.prompt_len
-                tel.prefill_chunk(s, st.req.rid, st.prefill_pos, g, w0)
-
-        w1 = tel.now()
-        t0, seq = self.kv.serve_step(toks, clen, start, reset, emit0,
-                                     dec_mask, self._next)
-        self._next = jnp.where(jnp.asarray(emit0), t0, seq[:, -1])
-        self.programs_run += 1
-        self.prefill_tokens += int(clen.sum())
-        w2 = tel.now()
-        tel.decode_microsteps(len(dec), self.tokens_per_program, w1)
-        self._overlap_host_work()      # under the dispatched device step
-        # pack next step's chunk grants now, keyed on the exact inputs
-        # _plan_chunks will see; a key hit is bit-identical to recomputing
-        # (pack_chunks is pure), a miss (admission/preemption changed the
-        # picture) silently falls through to the normal recompute
-        nxt_rem = [r for s, g in zip(pre, grants)
-                   for r in [self.sched.active[s].prompt_len
-                             - (self.sched.active[s].prefill_pos + g)]
-                   if r > 0]
-        if nxt_rem:
-            t = tel.now()
-            ndec = len(dec) + sum(1 for s, g in zip(pre, grants) if emit0[s])
-            key = (self.chunk_budget, self.chunk_width,
-                   self.tokens_per_program * ndec, tuple(nxt_rem))
-            self._pack_memo = (key, pack_chunks(
-                self.chunk_budget, self.chunk_width,
-                self.tokens_per_program * ndec, list(nxt_rem)))
-            tel.overlap("pack", tel.now() - t)
-        return ("chunk", (pre, grants, dec, emit0, t0, seq, w0, w1, w2))
+        B, W, K = self.n_slots, self.chunk_width, self.tokens_per_program
+        with tel.span("engine.plan") as plan:
+            dec, pre, grants = self._plan_chunks()
+            tel.pack(self.chunk_budget, K * len(dec), int(sum(grants)),
+                     plan.t0)
+            toks = np.zeros((B, W), np.int32)
+            clen = np.zeros(B, np.int32)
+            start = np.zeros(B, np.int32)
+            reset = np.zeros(B, bool)
+            emit0 = np.zeros(B, bool)
+            dec_mask = np.zeros(B, bool)
+            for s in dec:
+                st = self.sched.active[s]
+                start[s] = st.prompt_len + st.produced - 1   # next write pos
+                dec_mask[s] = True
+            for s, g in zip(pre, grants):
+                st = self.sched.active[s]
+                start[s] = st.prefill_pos
+                clen[s] = g
+                toks[s, :g] = st.req.prompt[st.prefill_pos:st.prefill_pos + g]
+                if g:
+                    reset[s] = st.fresh
+                    st.fresh = False
+                    emit0[s] = st.prefill_pos + g == st.prompt_len
+                    tel.prefill_chunk(s, st.req.rid, st.prefill_pos, g,
+                                      plan.t0)
+        self._count_positions(
+            int(clen.sum()) + sum(min(K, self.sched.active[s].remaining)
+                                  for s in dec),
+            B * (W + K))
+        with tel.span("engine.dispatch", kind="serve",
+                      program=self.programs_run) as disp:
+            t0, seq = self.kv.serve_step(toks, clen, start, reset, emit0,
+                                         dec_mask, self._next)
+            self._next = jnp.where(jnp.asarray(emit0), t0, seq[:, -1])
+            self.programs_run += 1
+            self.prefill_tokens += int(clen.sum())
+        tel.decode_microsteps(len(dec), K, disp.t0)
+        with tel.span("engine.overlap"):
+            self._overlap_host_work()  # under the dispatched device step
+            # pack next step's chunk grants now, keyed on the exact inputs
+            # _plan_chunks will see; a key hit is bit-identical to
+            # recomputing (pack_chunks is pure), a miss (admission/preemption
+            # changed the picture) silently falls through to the normal
+            # recompute
+            nxt_rem = [r for s, g in zip(pre, grants)
+                       for r in [self.sched.active[s].prompt_len
+                                 - (self.sched.active[s].prefill_pos + g)]
+                       if r > 0]
+            if nxt_rem:
+                t = tel.now()
+                ndec = len(dec) + sum(1 for s, g in zip(pre, grants)
+                                      if emit0[s])
+                key = (self.chunk_budget, self.chunk_width, K * ndec,
+                       tuple(nxt_rem))
+                self._pack_memo = (key, pack_chunks(
+                    self.chunk_budget, self.chunk_width, K * ndec,
+                    list(nxt_rem)))
+                tel.overlap("pack", tel.now() - t)
+        return ("chunk", (pre, grants, dec, emit0, t0, seq, plan, disp))
 
     def _chunk_end(self, data, now_fn: Callable[[], float]
                    ) -> List[Completion]:
         """Commit half of the chunked serve step: sync, harvest prefill
         first-tokens and decode tokens, evict finished slots."""
-        pre, grants, dec, emit0, t0, seq, w0, w1, w2 = data
+        pre, grants, dec, emit0, t0, seq, plan, disp = data
         tel = self.tel
-        t0_host = seq_host = None
-        if not self.linkage.ret_async:
-            t0_host, seq_host = np.asarray(t0), np.asarray(seq)
-        w3 = tel.now()
-        now = now_fn()
-        finished = []
-        for s, g in zip(pre, grants):
-            st = self.sched.active[s]
-            st.prefill_pos += g
-            if not emit0[s]:
-                continue
-            # the chunk that completed the prompt yields generated token #1
-            first = t0[s:s + 1] if t0_host is None else t0_host[s:s + 1]
-            st.chunks.append(first)
-            if (t0_host is not None and st.req.eos_id is not None
-                    and int(first[0]) == st.req.eos_id):
-                st.eos_seen = True
-            st.first_token_s = st.prefill_done_s = now
-            st.note_emit(now)
-            st.produced = 1
-            tel.state(st.req.rid, "decoding", now)
-            if st.remaining == 0 or st.eos_seen:
-                finished.append(self._finalize(s, now_fn))
-        finished += self._harvest_decode(dec, seq, seq_host, now_fn)
-        tel.step("serve_chunk", self.programs_run, w0, w1 - w0, w2 - w1,
-                 w3 - w2, tel.now() - w3, queued=self.sched.n_queued,
-                 active=len(self.sched.active),
-                 swapped=len(self.sched.swapped),
-                 program=self._labels["serve_chunk"])
+        with tel.span("engine.harvest") as harvest:
+            self._waited = 0.0
+            t0_host = seq_host = None
+            if not self.linkage.ret_async:
+                t = tel.now()
+                t0_host, seq_host = np.asarray(t0), np.asarray(seq)
+                self._waited += tel.now() - t
+            now = now_fn()
+            finished = []
+            for s, g in zip(pre, grants):
+                st = self.sched.active[s]
+                st.prefill_pos += g
+                if not emit0[s]:
+                    continue
+                # the chunk that completed the prompt yields generated
+                # token #1
+                first = t0[s:s + 1] if t0_host is None else t0_host[s:s + 1]
+                st.chunks.append(first)
+                if (t0_host is not None and st.req.eos_id is not None
+                        and int(first[0]) == st.req.eos_id):
+                    st.eos_seen = True
+                st.first_token_s = st.prefill_done_s = now
+                st.note_emit(now)
+                st.produced = 1
+                tel.state(st.req.rid, "decoding", now)
+                if st.remaining == 0 or st.eos_seen:
+                    finished.append(self._finalize(s, now_fn))
+            finished += self._harvest_decode(dec, seq, seq_host, now_fn)
+        self._step_event("serve_chunk", plan, disp, harvest)
         return finished
 
     def _finalize(self, slot: int,
@@ -790,7 +835,9 @@ class ServeEngine:
         st = self.sched.release(slot)
         self.kv.release(slot)                       # paged: free blocks now
         # RET mode synchronizes here, once per completed request
-        tokens = np.concatenate([np.asarray(c) for c in st.chunks])
+        with self.tel.span("engine.finalize", rid=st.req.rid) as sync:
+            tokens = np.concatenate([np.asarray(c) for c in st.chunks])
+        self._waited += sync.t1 - sync.t0
         if st.req.eos_id is not None:
             hits = np.nonzero(tokens == st.req.eos_id)[0]
             if hits.size:
@@ -823,28 +870,33 @@ class ServeEngine:
         replicas. ``tick_commit(tick_dispatch(now))`` run back to back is
         exactly the single-engine tick."""
         finished = []
-        self.tel.profile_tick(self.programs_run)
-        self._drain_swaps()          # step boundary: complete deferred copies
-        self._resume_swapped()
-        while self.sched.can_admit() and not self.sched.swapped:
-            # swapped slots are the head of the line: fresh admissions wait
-            head = self.sched.peek()
-            if not self.kv.has_room(int(head.prompt.shape[0])):
-                break                # FIFO: wait for blocks, don't skip ahead
-            if self.chunked:
-                self._admit_chunked(now_fn)   # bookkeeping only, no program
-            else:
-                finished += self._admit(now_fn)
+        tel = self.tel
+        tel.profile_tick(self.programs_run)
+        # the tick's span opens here and closes in tick_commit
+        tick = tel.span("engine.tick").__enter__()
+        with tel.span("engine.admit"):
+            self._drain_swaps()      # step boundary: complete deferred copies
+            self._resume_swapped()
+            while self.sched.can_admit() and not self.sched.swapped:
+                # swapped slots are the head of the line: fresh admissions
+                # wait
+                head = self.sched.peek()
+                if not self.kv.has_room(int(head.prompt.shape[0])):
+                    break            # FIFO: wait for blocks, don't skip ahead
+                if self.chunked:
+                    self._admit_chunked(now_fn)  # bookkeeping only
+                else:
+                    finished += self._admit(now_fn)
         pend = None
         if self.sched.active:
             pend = (self._chunk_begin(now_fn) if self.chunked
                     else self._step_begin(now_fn))
-        return finished, pend
+        return finished, pend, tick
 
     def tick_commit(self, ticket, now_fn) -> List[Completion]:
         """Commit half of one engine tick: block on the dispatched program,
         harvest, and feed the TTFT tuner."""
-        finished, pend = ticket
+        finished, pend, tick = ticket
         finished = list(finished)
         if pend is not None:
             finished += self._step_end(pend, now_fn)
@@ -854,6 +906,7 @@ class ServeEngine:
                 self.chunk_budget = self.tuner.observe(c.ttft_s)
                 self.tel.budget_adjust(old, self.chunk_budget,
                                        self.tel.now())
+        tick.__exit__(None, None, None)
         return finished
 
     # -- fleet: prefill/decode disaggregation handoff -----------------------
@@ -990,6 +1043,8 @@ class ServeEngine:
             # prompts and producing tokens (chunked scheduling observable)
             "prefill_tokens": self.prefill_tokens,
             "decode_tokens": self.decode_tokens,
+            "positions_useful": self.positions_useful,
+            "positions_computed": self.positions_computed,
         }
         if self.programs_run:
             u["prefill_tokens_per_step"] = round(
@@ -1042,6 +1097,8 @@ class ServeEngine:
         self.swap_resumes = 0
         self.prefill_tokens = 0
         self.decode_tokens = 0
+        self.positions_useful = 0
+        self.positions_computed = 0
         self.spec_steps = 0
         self.spec_draft_tokens = 0
         self.spec_accepted_tokens = 0

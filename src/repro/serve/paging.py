@@ -1534,9 +1534,10 @@ class PagedKV:
         if self._pending_publish:
             # the updated cache now carries this step's chunk writes; skip
             # nodes a preemption/eviction replan removed in the meantime
-            nodes, self._pending_publish = self._pending_publish, []
-            self._publish([n for n in nodes
-                           if self.index._by_block.get(n.block) is n])
+            with self.tel.span("kv.publish"):
+                nodes, self._pending_publish = self._pending_publish, []
+                self._publish([n for n in nodes
+                               if self.index._by_block.get(n.block) is n])
         return t0, seq
 
     # -- speculative decode -------------------------------------------------

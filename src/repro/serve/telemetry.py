@@ -11,7 +11,8 @@ Three cooperating pieces:
 ``TraceRecorder``
     An append-only store of typed, timestamped events from every engine
     subsystem — ``engine_step`` (with a pack / dispatch / device /
-    host-bookkeeping phase breakdown), ``prefill_chunk``,
+    host-bookkeeping phase breakdown), ``host_span`` (one phase of an
+    engine tick, see ``Telemetry.span``), ``prefill_chunk``,
     ``decode_microsteps``, ``verify_window``, ``swap_out`` / ``swap_in`` /
     ``demote`` / ``promote``, ``preempt``, ``admit`` / ``complete``,
     ``pack``, ``budget`` — plus per-request lifecycle *spans* (``queued →
@@ -35,11 +36,14 @@ Three cooperating pieces:
 ``Telemetry``
     The hook bundle the engine (and the KV backends) actually call. Each
     hook updates the recorder and/or the registry; the module-level
-    ``NULL_TELEMETRY`` singleton is the zero-cost disabled implementation —
-    every hook is a no-op and ``now()`` returns 0.0 without reading a
-    clock, so a disabled engine takes no timestamps and allocates nothing
+    ``NULL_TELEMETRY`` singleton is the disabled implementation — every
+    hook is a no-op and ``now()`` returns 0.0 without reading a clock
     (bit-identical token streams and <2% measured overhead even when
-    enabled; see bench_serving's tracing-overhead rows).
+    enabled; see bench_serving's tracing-overhead rows). The one thing a
+    disabled bundle still does is enter each ``span``'s
+    ``jax.profiler.TraceAnnotation``: an inert ``TraceMe`` unless a
+    profiler session is running, and then the engine's tick phases land
+    on the profiler's timeline beside the device ops they drive.
 
 The span state machine mirrors the scheduler's legal transitions exactly
 (``SPAN_TRANSITIONS``); ``validate_spans`` checks a trace against it and
@@ -53,6 +57,8 @@ import math
 import re
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 # ---------------------------------------------------------------------------
 # Event taxonomy
 # ---------------------------------------------------------------------------
@@ -65,6 +71,9 @@ EVENT_SCHEMA: Dict[str, frozenset] = {
                               "device_s", "host_s"}),
     # child duration event of an engine_step (one per non-empty phase)
     "step_phase": frozenset({"phase"}),
+    # one phase of an engine tick (``Telemetry.span``): ``name`` is the
+    # span's (engine.tick, engine.plan, ...), ``dur`` its length
+    "host_span": frozenset({"name"}),
     # one granted prompt chunk entering the device this step
     "prefill_chunk": frozenset({"slot", "rid", "start", "len"}),
     # the decode half of a step: how many slots advanced by k tokens
@@ -295,9 +304,13 @@ def chrome_trace(events: Iterable[dict]) -> dict:
                         "args": dict({"etype": "span", "rid": rid,
                                       "state": state}, **eng)})
             open_spans[rid] = (state, ts)
-        elif et in ("engine_step", "step_phase"):
-            name = (et if et == "engine_step"
-                    else f"phase:{ev['args']['phase']}")
+        elif et in ("engine_step", "step_phase", "host_span"):
+            if et == "engine_step":
+                name = et
+            elif et == "host_span":
+                name = ev["args"]["name"]
+            else:
+                name = f"phase:{ev['args']['phase']}"
             out.append({"ph": "X", "cat": "engine", "name": name,
                         "pid": pid, "tid": 0, "ts": us,
                         "dur": ev.get("dur", 0.0) * 1e6,
@@ -635,6 +648,39 @@ class MetricsRegistry:
 # Telemetry: the hook bundle the engine calls
 # ---------------------------------------------------------------------------
 
+class Span:
+    """One phase of an engine tick on the host (``Telemetry.span``).
+
+    Entering it enters a ``jax.profiler.TraceAnnotation`` named ``name``
+    (its ``args`` encoded in the name as ``name#k=v,...#``), so whenever a
+    profiler session is running the span lands in the trace on the same
+    timeline as the device ops. ``t0`` and ``t1`` are the bundle's ``now()``
+    at entry and exit (0.0 on ``NULL_TELEMETRY``); the engine takes its
+    ``engine_step`` phases from them, and a recording bundle appends a
+    ``host_span`` event between them. Usable as a context manager, or
+    entered and exited by hand where a phase spans two calls (the engine
+    tick: ``tick_dispatch`` to ``tick_commit``)."""
+
+    __slots__ = ("tel", "name", "args", "t0", "t1", "_me")
+
+    def __init__(self, tel: "Telemetry", name: str, args: Dict[str, Any]):
+        self.tel, self.name, self.args = tel, name, args
+        self.t0 = self.t1 = 0.0
+        self._me = TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "Span":
+        self._me.__enter__()
+        self.t0 = self.tel.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = self.tel.now()
+        self._me.__exit__(*exc)
+        if self.tel.trace is not None:
+            self.tel.trace.emit("host_span", self.t0, dur=self.t1 - self.t0,
+                                name=self.name, **self.args)
+
+
 class Telemetry:
     """Bundles a ``TraceRecorder`` and/or ``MetricsRegistry`` behind the
     hook methods the engine and KV backends call.
@@ -763,6 +809,10 @@ class Telemetry:
             self.metrics.reset()
 
     # -- engine step --------------------------------------------------------
+
+    def span(self, name: str, **args) -> Span:
+        """A host span over one phase of the engine tick (see ``Span``)."""
+        return Span(self, name, args)
 
     def step(self, kind: str, step: int, t0: float, pack_s: float,
              dispatch_s: float, device_s: float, host_s: float,
@@ -992,9 +1042,11 @@ class Telemetry:
 
 
 class _NullTelemetry(Telemetry):
-    """The zero-cost disabled recorder: every hook is a no-op and ``now``
-    never reads a clock, so the engine's timestamp calls vanish. One
-    shared singleton (``NULL_TELEMETRY``) — never mutate it."""
+    """The disabled recorder: every hook is a no-op and ``now`` never reads
+    a clock, so the engine's timestamp calls vanish. ``span`` is inherited:
+    it still enters the profiler annotation (an inert ``TraceMe`` unless a
+    profiler session is running) and records nothing. One shared singleton
+    (``NULL_TELEMETRY``) — never mutate it."""
 
     active = False
 

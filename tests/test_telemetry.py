@@ -56,6 +56,7 @@ def _one_of_everything() -> TraceRecorder:
     rec.emit("admit", 0.1, rid=7, slot=0, prompt_len=16)
     rec.span(7, "prefilling", 0.1)
     rec.step("serve_chunk", 1, 0.2, 0.01, 0.02, 0.03, 0.04)
+    rec.emit("host_span", 0.2, dur=0.01, name="engine.plan")
     rec.emit("prefill_chunk", 0.2, slot=0, rid=7, start=0, len=6)
     rec.emit("pack", 0.2, budget=8, decode_tokens=2, granted=6)
     rec.emit("decode_microsteps", 0.3, slots=2, k=4)
@@ -351,6 +352,79 @@ def test_spec_decode_verify_windows(params):
     assert sum(w["args"]["accepted"] for w in wins) \
         == eng.spec_accepted_tokens
     validate_spans(tel.trace.events)
+
+
+def _counting_clock(step_s: float = 1e-3):
+    """A clock that advances ``step_s`` at every reading."""
+    n = iter(range(1 << 40))
+    return lambda: next(n) * step_s
+
+
+def test_host_spans_nest_in_the_tick_and_phases_add_up(params):
+    """Every engine_step's phases add up to the step; under ret_async its
+    device phase is exactly the engine.finalize spans inside it (the only
+    blocking waits on results), so zero on a step that finished nothing;
+    each phase span lies inside an engine.tick span."""
+    tel = Telemetry()
+    eng = _traced_engine(params, tel, chunked=True, chunk_budget=6)
+    assert eng.linkage.ret_async
+    reqs = synthetic_requests(4, prompt_len=16, max_new_tokens=8,
+                              vocab_size=CFG.vocab_size, seed=0)
+    eng.run(reqs, load="closed", clock=_counting_clock())
+    evs = tel.trace.events
+    validate_events(evs)
+    spans = [e for e in evs if e["type"] == "host_span"]
+    by = lambda n: [(e["ts"], e["ts"] + e.get("dur", 0.0)) for e in spans
+                    if e["args"]["name"] == n]
+    ticks, finals = by("engine.tick"), by("engine.finalize")
+    assert len(ticks) == len(by("engine.admit")) >= eng.programs_run
+    for name in ("engine.admit", "engine.plan", "engine.dispatch",
+                 "engine.overlap", "engine.harvest", "engine.finalize"):
+        assert by(name), name
+        for s, e in by(name):
+            assert any(a <= s and e <= b for a, b in ticks), name
+    steps = [e for e in evs if e["type"] == "engine_step"]
+    assert len(steps) == eng.programs_run
+    waited = 0
+    for st in steps:
+        a = st["args"]
+        phases = a["pack_s"] + a["dispatch_s"] + a["device_s"] + a["host_s"]
+        assert phases == pytest.approx(st["dur"], abs=1e-9)
+        assert min(a["pack_s"], a["dispatch_s"], a["device_s"],
+                   a["host_s"]) >= -1e-12
+        lo, hi = st["ts"], st["ts"] + st["dur"]
+        inside = sum(e - s for s, e in finals if lo <= s and e <= hi)
+        assert a["device_s"] == pytest.approx(inside, abs=1e-9)
+        waited += a["device_s"] > 0
+    assert 0 < waited < len(steps)
+
+
+def test_a_profiler_session_leaves_token_streams_unchanged(params, tmp_path):
+    """The engine's spans are profiler annotations even with telemetry off
+    (NULL_TELEMETRY): a profiler session records them, on the host plane,
+    and serving under one gives the same tokens."""
+    import glob
+    reqs = synthetic_requests(4, prompt_len=16, max_new_tokens=8,
+                              vocab_size=CFG.vocab_size, seed=0,
+                              shared_prefix_len=8)
+
+    def serve():
+        eng = _traced_engine(params, None, chunked=True, chunk_budget=6)
+        assert eng.tel is NULL_TELEMETRY
+        comps, _ = eng.run(reqs, load="closed")
+        return {c.rid: c.tokens.tolist() for c in comps}
+
+    off = serve()
+    with jax.profiler.trace(str(tmp_path)):
+        on = serve()
+    assert on == off
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    names = {e.name.split("#")[0] for plane in pd.planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for e in line.events}
+    assert {"engine.tick", "engine.plan", "engine.dispatch",
+            "engine.harvest", "kv.publish"} <= names
 
 
 # ---------------------------------------------------------------------------

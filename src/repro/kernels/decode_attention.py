@@ -24,13 +24,19 @@ NEG_INF = -1e30
 
 
 def flash_step(q, k, v, live, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
-               step, nt: int):
+               step, nt: int, active=None):
     """One online-softmax step of a (rows x tile) score block.
 
     q: (R, dh) f32 queries; k, v: (T, dh) f32 KV tile; live: bool mask
     broadcastable to (R, T). ``step`` is the grid index walking the KV tiles
     (0 .. nt-1): the first step resets the m/l/acc scratch, the last writes
     the normalized output to ``o_ref[0, 0]``.
+
+    ``active`` (a traced bool) runs the score-and-update part only where it
+    holds; the reset and the write still run on their steps. Without it
+    every step updates. Each of ``q, k, v, live`` may be given as a
+    function of no arguments, called only where the update runs, so that
+    a skipped step skips its loads too.
     """
     @pl.when(step == 0)
     def _init():
@@ -38,21 +44,29 @@ def flash_step(q, k, v, live, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # f32 operands, f32 products: the contract precision is pinned rather
-    # than left to the compiler's default
-    hi = jax.lax.Precision.HIGHEST
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=hi,
-                            preferred_element_type=jnp.float32) * scale
-    s = jnp.where(live, s, NEG_INF)
-    m_prev = m_ref[:, 0]
-    m_cur = jnp.maximum(m_prev, s.max(axis=-1))
-    corr = jnp.exp(m_prev - m_cur)
-    p = jnp.where(live, jnp.exp(s - m_cur[:, None]), 0.0)
-    l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), precision=hi,
-        preferred_element_type=jnp.float32)
-    m_ref[:, 0] = m_cur
+    def _update():
+        qv, kv, vv, lv = (x() if callable(x) else x for x in (q, k, v, live))
+        # f32 operands, f32 products: the contract precision is pinned
+        # rather than left to the compiler's default
+        hi = jax.lax.Precision.HIGHEST
+        s = jax.lax.dot_general(qv, kv, (((1,), (1,)), ((), ())),
+                                precision=hi,
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(lv, s, NEG_INF)
+        m_prev = m_ref[:, 0]
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1))
+        corr = jnp.exp(m_prev - m_cur)
+        p = jnp.where(lv, jnp.exp(s - m_cur[:, None]), 0.0)
+        l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=-1)
+        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+            p, vv, (((1,), (0,)), ((), ())), precision=hi,
+            preferred_element_type=jnp.float32)
+        m_ref[:, 0] = m_cur
+
+    if active is None:
+        _update()
+    else:
+        pl.when(active)(_update)
 
     @pl.when(step == nt - 1)
     def _finish():

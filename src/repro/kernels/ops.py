@@ -15,6 +15,7 @@ GQA groups on the ``"model"`` axis, exactly as the KV pools are sharded.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import (decode_attention as _dec, flash_attention as _fa,
@@ -88,18 +89,19 @@ def slot_decode_attention(q, ck, cv, slot_pos, pos, *, window: int = 0,
     return out[:, None]
 
 
-def _paged_call(kernel, mesh, q, kp, vp, tables, rowpos, ks, vs):
-    """Run a paged kernel ``kernel(q, kp, vp, tables, rowpos, ks, vs)``
-    (q heads on axis -2) on one device or per shard of ``mesh``: the block
-    table and the per-row positions are replicated, the pools and their
-    scale tables split by KV head."""
-    args = (q, kp, vp, tables, rowpos) + ((ks, vs) if ks is not None else ())
+def _paged_call(kernel, mesh, q, kp, vp, tables, rows, ks, vs):
+    """Run a paged kernel ``kernel(q, kp, vp, tables, *rows, *scales)`` (q
+    heads on axis -2) on one device or per shard of ``mesh``: the block
+    table and the per-row operands ``rows`` (positions, query counts) are
+    replicated, the pools and their scale tables split by KV head."""
+    scales = (ks, vs) if ks is not None else ()
     h = _head_axis(mesh, kp.shape[2])
     qs = P(*([None] * (q.ndim - 2)), h, None)
     kv = P(None, None, h, None)
-    specs = (qs, kv, kv, P(), P()) + ((P(None, h),) * 2 if ks is not None
-                                      else ())
-    return _per_shard(kernel, mesh, specs, qs)(*args)
+    specs = (qs, kv, kv, P()) + (P(),) * len(rows) + (P(None, h),) * len(
+        scales)
+    return _per_shard(kernel, mesh, specs, qs)(q, kp, vp, tables, *rows,
+                                               *scales)
 
 
 def paged_decode_attention(q, kp, vp, tables, pos, ks=None, vs=None, *,
@@ -116,25 +118,32 @@ def paged_decode_attention(q, kp, vp, tables, pos, ks=None, vs=None, *,
     """
     def kernel(*args):
         return _paged.paged_decode_attention(*args, interpret=_interpret())
-    out = _paged_call(kernel, mesh, q[:, 0], kp, vp, tables, pos, ks, vs)
+    out = _paged_call(kernel, mesh, q[:, 0], kp, vp, tables, (pos,), ks, vs)
     return out[:, None]
 
 
 def paged_prefill_attention(q, kp, vp, tables, start, ks=None, vs=None, *,
-                            mesh=None):
+                            qlen=None, mesh=None):
     """Paged chunked-prefill: every slot's prompt chunk attends over its
     resident block chain (the rectangular generalization of paged decode).
 
     q: (B,W,HQ,dh) chunk queries (the chunk's own K/V already scattered into
     the pools); kp/vp: (P+1,bs,HKV,dh) physical pools; tables: (B,nb) int32
     logical->physical map; start: (B,) first chunk position per row.
+    qlen: (B,) real queries per row (default W); each row walks its blocks
+    only up to that of its last real query.
     ks/vs: optional (P+1,HKV) f32 per-block scales when the pools are
     quantized — dequant fuses into the kernel.
     """
-    def kernel(*args):
-        return _paged_pf.paged_prefill_attention(*args,
-                                                 interpret=_interpret())
-    return _paged_call(kernel, mesh, q, kp, vp, tables, start, ks, vs)
+    if qlen is None:
+        qlen = jnp.full(start.shape, q.shape[1], jnp.int32)
+
+    def kernel(q, kp, vp, tables, start, qlen, *scales):
+        return _paged_pf.paged_prefill_attention(
+            q, kp, vp, tables, start, *scales, qlen=qlen,
+            interpret=_interpret())
+    return _paged_call(kernel, mesh, q, kp, vp, tables, (start, qlen), ks,
+                       vs)
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,

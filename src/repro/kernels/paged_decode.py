@@ -8,13 +8,15 @@ the grid's inner axis walks the slot's *logical* blocks, a scalar-prefetched
 block table translates each step to a physical pool row in the BlockSpec
 index map, and the per-row position rides the same scalar-prefetch lane, so
 the live-lane mask ``k_pos <= pos`` is formed from grid coordinates and no
-mask array is read from HBM.
+mask array is read from HBM. With one real query a row, the walk stops at
+block ``pos // bs``.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels.paged_prefill import paged_prefill_attention
 
@@ -32,4 +34,5 @@ def paged_decode_attention(q, kp, vp, tables, pos, ks=None, vs=None, *,
     dequant fuses into the kernel.
     """
     return paged_prefill_attention(q[:, None], kp, vp, tables, pos, ks, vs,
+                                   qlen=jnp.ones_like(pos),
                                    interpret=interpret)[:, 0]

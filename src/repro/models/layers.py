@@ -634,7 +634,8 @@ def attention_serve_chunk_paged(params: Params, x: jax.Array, cache: Params,
     positions above every real query, so it is invisible by the same causal
     comparison. The pallas path is the scalar-prefetched block-table flash
     kernel ``repro.kernels.paged_prefill`` (the roadmap's paged prefill
-    kernel).
+    kernel), told ``clen`` so that each row walks its blocks only up to
+    that of its last real query.
     """
     B, W, _ = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -668,7 +669,7 @@ def attention_serve_chunk_paged(params: Params, x: jax.Array, cache: Params,
     if opts.attn_impl == "pallas":
         from repro.kernels import ops as kops
         out = kops.paged_prefill_attention(q, kp, vp, tables, start, ks, vs,
-                                           mesh=opts.mesh)
+                                           qlen=clen, mesh=opts.mesh)
     else:
         # gather fallback: assemble each row's logical view and mask by
         # position — same shapes and reductions as the dense chunk path

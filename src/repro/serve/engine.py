@@ -235,6 +235,11 @@ class ServeEngine:
         # verify positions emitting one)
         self.positions_computed = 0
         self.positions_useful = 0
+        # KV blocks of the paged attention kernel's grid in the chunk and
+        # verify passes (slots x blocks a row), and those of them at or
+        # below a row's last real query, the blocks it attends
+        self.chunk_blocks_grid = 0
+        self.chunk_blocks_live = 0
         self._waited = 0.0           # this step's blocking waits on results
 
     def _bucket(self, n: int) -> int:
@@ -479,6 +484,17 @@ class ServeEngine:
         self.positions_useful += useful
         self.positions_computed += computed
 
+    def _count_blocks(self, start: np.ndarray, clen: np.ndarray) -> None:
+        """A paged chunk or verify pass's KV blocks: the kernel's whole
+        grid, and the blocks up to each row's last real query,
+        ``ceil((start + clen) / bs)`` over the rows with a chunk."""
+        if self.kv.kind != "paged":
+            return
+        real = clen > 0
+        ends = start[real].astype(np.int64) + clen[real]
+        self.chunk_blocks_live += int((-(-ends // self.kv.bs)).sum())
+        self.chunk_blocks_grid += len(clen) * self.kv.nb
+
     def _step_event(self, kind: str, plan, disp, harvest) -> None:
         """The ``engine_step`` event of one program, from its spans: pack is
         everything before the dispatch, device the blocking waits on
@@ -566,6 +582,7 @@ class ServeEngine:
             finished = self._harvest_spec(order, start, nxt_host, out,
                                           n_emit, now_fn)
         self._count_positions(int(np.asarray(n_emit).sum()), B * W)
+        self._count_blocks(start, clen)
         self._step_event("verify", plan, disp, harvest)
         return finished
 
@@ -762,6 +779,7 @@ class ServeEngine:
             int(clen.sum()) + sum(min(K, self.sched.active[s].remaining)
                                   for s in dec),
             B * (W + K))
+        self._count_blocks(start, clen)
         with tel.span("engine.dispatch", kind="serve",
                       program=self.programs_run) as disp:
             t0, seq = self.kv.serve_step(toks, clen, start, reset, emit0,
@@ -1045,6 +1063,8 @@ class ServeEngine:
             "decode_tokens": self.decode_tokens,
             "positions_useful": self.positions_useful,
             "positions_computed": self.positions_computed,
+            "chunk_blocks_live": self.chunk_blocks_live,
+            "chunk_blocks_grid": self.chunk_blocks_grid,
         }
         if self.programs_run:
             u["prefill_tokens_per_step"] = round(
@@ -1099,6 +1119,8 @@ class ServeEngine:
         self.decode_tokens = 0
         self.positions_useful = 0
         self.positions_computed = 0
+        self.chunk_blocks_live = 0
+        self.chunk_blocks_grid = 0
         self.spec_steps = 0
         self.spec_draft_tokens = 0
         self.spec_accepted_tokens = 0

@@ -22,6 +22,27 @@ def _tol(dt):
         else dict(atol=3e-5, rtol=3e-5)
 
 
+def _chunk_rows(starts, W):
+    """A paged chunk case's rows: each entry of ``starts`` is a start (all
+    W queries real) or a (start, real queries) pair. Returns the start
+    array, the qlen array (None when every row is whole) and a (B, W) mask
+    of the real query rows."""
+    pairs = [s if isinstance(s, tuple) else (s, W) for s in starts]
+    start = jnp.asarray(np.array([s for s, _ in pairs], np.int32))
+    ql = np.array([n for _, n in pairs], np.int32)
+    real = np.arange(W)[None] < ql[:, None]
+    return start, (None if real.all() else jnp.asarray(ql)), real
+
+
+def _assert_real_rows(out, ref, real, **tol):
+    """Real query rows match the oracle; a row with none writes zeros
+    (padding query rows are garbage by contract)."""
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_allclose(out[real], ref[real], **tol)
+    for b in np.flatnonzero(~real.any(axis=1)):
+        assert not out[b].any()
+
+
 @pytest.mark.parametrize("B,S,HQ,HKV,dh,causal,window,dt", [
     (2, 128, 4, 2, 64, True, 0, jnp.float32),
     (1, 200, 8, 8, 80, True, 0, jnp.float32),      # ragged, MHA, odd dh
@@ -212,6 +233,14 @@ def test_flash_attention_grad_matches_ref():
     (5, 8, 2, 1, 1, 8, 2, 80, (9,), jnp.float32),       # chunk length 1
     (6, 8, 3, 3, 16, 4, 1, 128, (0, 8, 3), jnp.float32),  # MQA, W=2 blocks
     (7, 16, 2, 2, 16, 6, 3, 64, (16, 15), jnp.bfloat16),  # boundary starts
+    # ragged real queries, (start, qlen): no chunk; last query on a block's
+    # end with padding queries in the next block; mid-block; on a block's
+    # first lane; in block nb-1
+    (9, 8, 3, 5, 8, 4, 2, 64, ((0, 0), (5, 3), (9, 2), (10, 7), (17, 7)),
+     jnp.float32),
+    (7, 8, 3, 4, 1, 6, 3, 64, ((12, 1), (3, 0), (16, 1), (23, 1)),
+     jnp.float32),                                       # W=1
+    (7, 16, 2, 2, 16, 6, 3, 64, ((0, 16), (20, 5)), jnp.bfloat16),
 ])
 def test_paged_prefill_kernel_parity(P1, bs, nb, B, W, HQ, HKV, dh, starts,
                                      dt):
@@ -224,11 +253,39 @@ def test_paged_prefill_kernel_parity(P1, bs, nb, B, W, HQ, HKV, dh, starts,
     rng = np.random.default_rng(P1 * bs + B + W)
     tables = jnp.asarray(np.stack(
         [rng.permutation(P1)[:nb] for _ in range(B)]).astype(np.int32))
-    start = jnp.asarray(np.array(starts, np.int32))
-    out = paged_prefill_attention(q, kp, vp, tables, start, interpret=True)
+    start, qlen, real = _chunk_rows(starts, W)
+    out = paged_prefill_attention(q, kp, vp, tables, start, qlen=qlen,
+                                  interpret=True)
     ref = kref.paged_prefill_attention_ref(q, kp, vp, tables, start)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), **_tol(dt))
+    _assert_real_rows(out, ref, real, **_tol(dt))
+
+
+@pytest.mark.parametrize("kvd", ["float32", "int8"])
+def test_paged_prefill_walk_bound_is_exact(kvd):
+    """Bounding the block walk by each row's real queries skips only blocks
+    those queries mask whole: their rows equal the full walk's bit for
+    bit."""
+    from repro.kernels.paged_prefill import paged_prefill_attention
+    P1, bs, nb, B, W, HQ, HKV, dh = 13, 8, 6, 4, 8, 4, 2, 64
+    ks_ = jax.random.split(KEY, 3)
+    kp = jax.random.normal(ks_[0], (P1, bs, HKV, dh), jnp.float32)
+    vp = jax.random.normal(ks_[1], (P1, bs, HKV, dh), jnp.float32)
+    q = jax.random.normal(ks_[2], (B, W, HQ, dh), jnp.float32)
+    scales = ()
+    if kvd == "int8":
+        kp, k_s = _quantize_pool(kp, kvd)
+        vp, v_s = _quantize_pool(vp, kvd)
+        scales = (k_s, v_s)
+    rng = np.random.default_rng(5)
+    tables = jnp.asarray(np.stack(
+        [rng.permutation(P1)[:nb] for _ in range(B)]).astype(np.int32))
+    start, qlen, real = _chunk_rows(((3, 5), (17, 8), (30, 1), (40, 3)), W)
+    full = paged_prefill_attention(q, kp, vp, tables, start, *scales,
+                                   interpret=True)
+    bound = paged_prefill_attention(q, kp, vp, tables, start, *scales,
+                                    qlen=qlen, interpret=True)
+    np.testing.assert_array_equal(np.asarray(bound)[real],
+                                  np.asarray(full)[real])
 
 
 def test_paged_prefill_width_one_matches_decode_kernel():
@@ -424,6 +481,9 @@ def test_paged_decode_quantized_parity(P1, bs, nb, B, HQ, HKV, dh, lives,
     (5, 8, 2, 1, 1, 8, 2, 80, (9,), "int8"),        # W=1 decode edge, odd dh
     (9, 16, 2, 2, 7, 6, 3, 64, (5, 17), "fp8"),
     (5, 8, 2, 1, 1, 8, 2, 80, (9,), "fp8"),
+    # ragged real queries, (start, qlen), as in the unquantized cases
+    (9, 8, 3, 4, 8, 4, 2, 64, ((0, 0), (5, 3), (10, 7), (17, 7)), "int8"),
+    (5, 8, 2, 2, 1, 8, 2, 80, ((9, 1), (4, 0)), "int8"),          # W=1
 ])
 def test_paged_prefill_quantized_parity(P1, bs, nb, B, W, HQ, HKV, dh,
                                         starts, kvd):
@@ -437,16 +497,15 @@ def test_paged_prefill_quantized_parity(P1, bs, nb, B, W, HQ, HKV, dh,
     rng = np.random.default_rng(P1 * bs + B + W)
     tables = jnp.asarray(np.stack(
         [rng.permutation(P1)[:nb] for _ in range(B)]).astype(np.int32))
-    start = jnp.asarray(np.array(starts, np.int32))
+    start, qlen, real = _chunk_rows(starts, W)
     out = paged_prefill_attention(q, kp, vp, tables, start, ks, vs,
-                                  interpret=True)
+                                  qlen=qlen, interpret=True)
     ref_q = kref.paged_prefill_attention_ref(q, kp, vp, tables, start,
                                              ks=ks, vs=vs)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_q),
-                               atol=3e-5, rtol=3e-5)
+    _assert_real_rows(out, ref_q, real, atol=3e-5, rtol=3e-5)
     ref_f = kref.paged_prefill_attention_ref(q, kpf, vpf, tables, start)
-    assert float(np.max(np.abs(np.asarray(out) - np.asarray(ref_f)))) \
-        <= _QTOL[kvd]
+    assert float(np.max(np.abs(np.asarray(out)[real]
+                               - np.asarray(ref_f)[real]))) <= _QTOL[kvd]
 
 
 def test_paged_prefill_quantized_width_one_matches_decode_kernel():

@@ -88,10 +88,11 @@ def test_paged_decode_compiles(sd, kv_dtype):
 def test_paged_prefill_compiles(sd, kv_dtype, width):
     from repro.kernels.paged_prefill import paged_prefill_attention
     qdt, pool, scales = _kv_operands(sd, kv_dtype)
-    _compile(lambda q, kp, vp, t, st, *s: paged_prefill_attention(
-                 q, kp, vp, t, st, *s),
+    _compile(lambda q, kp, vp, t, st, ql, *s: paged_prefill_attention(
+                 q, kp, vp, t, st, *s, qlen=ql),
              sd((B, width, HQ, DH), qdt), pool, pool,
-             sd((B, NB), jnp.int32), sd((B,), jnp.int32), *scales)
+             sd((B, NB), jnp.int32), sd((B,), jnp.int32),
+             sd((B,), jnp.int32), *scales)
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])

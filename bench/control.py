@@ -44,13 +44,13 @@ def readings(cell) -> dict:
     cell.release()
     seqs, rows, toks = reference.served_rows(
         [prompts[r.rid] for r in picked], [r.tokens for r in picked])
-    ref = reference.logits(cell.cfg, cell.seed, seqs, rows, "f32")
+    ref = cell.desc.logits(cell.cfg, cell.seed, seqs, rows, "f32")
     best = np.asarray(ref.argmax(axis=1))
     out = {"tokens": int(len(toks)), "requests": len(picked),
            "program": float(reference.gap_of(ref, toks).max()),
            "program_flips": int((best != toks).sum())}
     for mode in CONTROLS:
-        ctl = reference.logits(cell.cfg, cell.seed, seqs, rows, mode)
+        ctl = cell.desc.logits(cell.cfg, cell.seed, seqs, rows, mode)
         pick = np.asarray(ctl.argmax(axis=1))
         del ctl
         out[mode] = float(reference.gap_of(ref, pick).max())

@@ -3,8 +3,9 @@ and the check against the plain reference.
 
 The system under test is ``repro.serve.ServeEngine`` over ``PagedKV`` with
 chunked prefill, built as the configuration file says. The benchmark makes
-its weights (``program_params``), its traffic (``bench.traffic``) and its
-reference (``bench.reference``); from the program it takes the engine, its
+its weights (``program_params``) and its traffic (``bench.traffic``); the
+configuration's model description (``spec.model``) gives its architecture,
+its reference and its work counts. From the program it takes the engine, its
 counters and the names its programs and kernels carry in the trace.
 """
 from __future__ import annotations
@@ -20,7 +21,7 @@ from typing import Optional
 
 import numpy as np
 
-from bench import flops, reference, spec, traffic, weights
+from bench import reference, spec, traffic, weights
 from bench.window import Window
 
 #: first request id of the warm-up requests, above any the traffic issues
@@ -55,10 +56,7 @@ class CompileCounter:
 
 def arch_config(cfg: dict):
     """The ``repro`` architecture config the file describes."""
-    from repro.configs import get_config
-    base = get_config(cfg["arch"])
-    return dataclasses.replace(base, **{spec.ARCH_KEYS[k]: cfg[k]
-                                        for k in spec.ARCH_KEYS})
+    return spec.model(cfg).arch_config(cfg)
 
 
 def _path(kp) -> str:
@@ -99,7 +97,8 @@ class Cell:
         self.mix = spec.load_traffic(self.w["traffic"], root)
         self.seed, self.seconds, self.trace = seed, seconds, trace
         self.start = process_start or time.monotonic()
-        self.sizes = reference.sizes(self.cfg)
+        self.desc = spec.model(self.cfg, root)
+        self.sizes = self.desc.sizes(self.cfg)
         self.fault = None           # tests: breaks the timed path
 
     # -- set-up -------------------------------------------------------------
@@ -119,7 +118,7 @@ class Cell:
                           self.cfg["matmul_precision"])
         e = self.cfg["engine"]
         self.devices = devices
-        self.arch = arch_config(self.cfg)
+        self.arch = self.desc.arch_config(self.cfg)
         data, model = e["mesh"]
         self.mesh = (make_host_mesh(data=data, model=model)
                      if data * model > 1 else None)
@@ -206,7 +205,7 @@ class Cell:
             records=list(self.win.records.values()), progs=self.win.progs,
             t0=self.win.t0, end=self.win.end, drained=self.drained,
             counters=self.counters, trace=red, cfg=self.cfg, engine=e,
-            model=flops.Model.of(self.sizes, kv_bytes=4),
+            model=self.desc.work(self.cfg),
             chips=self.w["chips"], peaks=peaks,
             window_compiles=self.window_compiles,
             e2e=self.win.metrics())
@@ -266,7 +265,7 @@ class Cell:
             return False, {"token_ids_in_vocab": {"value": 0, "limit": 1}}, 0
         seqs, rows, toks = reference.served_rows(
             [prompts[r.rid] for r in picked], served)
-        ref = reference.logits(self.cfg, self.seed, seqs, rows, "f32")
+        ref = self.desc.logits(self.cfg, self.seed, seqs, rows, "f32")
         gap = reference.gap_of(ref, toks)
         worst = float(np.max(gap))
         ok = bool(np.isfinite(gap).all()) and worst <= limit

@@ -8,6 +8,10 @@ and makes its own weights from the seed (``bench.weights``), layer by layer,
 so that a whole model never has to fit at once. It imports nothing of the
 serving program and takes nothing the program made.
 
+``forward`` runs any layer pattern with this embedding and head; ``layer``
+is the dense decoder's layer. A model description that is not the dense
+decoder (``bench/models``) passes ``forward`` its own layer functions.
+
 Several sequences are packed into one row: a token attends to the tokens of
 its own sequence at positions up to its own. The packed row is padded to a
 multiple of ``PAD`` so that few shapes compile.
@@ -23,7 +27,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,9 +66,10 @@ def layer_shapes(s: dict) -> Dict[str, Tuple[int, ...]]:
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("shapes", "dtype"))
-def _layer_weights(key, layer, shapes, dtype):
-    return {name: weights.leaf(key, f"blocks/0/{name}", shape,
+@functools.partial(jax.jit, static_argnames=("shapes", "dtype", "block"))
+def _layer_weights(key, layer, shapes, dtype, block=0):
+    """Layer ``layer`` of the program's stack ``blocks/<block>``."""
+    return {name: weights.leaf(key, f"blocks/{block}/{name}", shape,
                                layer=layer).astype(dtype)
             for name, shape in shapes}
 
@@ -93,7 +98,9 @@ def _rope(x, pos, theta):
 
 
 @functools.partial(jax.jit, static_argnames=("s", "mode"))
-def _layer(x, w, pos, seg, s, mode):
+def layer(x, w, pos, seg, s, mode):
+    """One dense layer (the weights of ``layer_shapes``) over the packed
+    row: ``pos`` and ``seg`` are each token's position and sequence."""
     dtype, prec = MODES[mode]
     s = dict(s)
     dot = functools.partial(jnp.dot, precision=prec)
@@ -151,12 +158,19 @@ def pack(seqs: Sequence[np.ndarray]):
     return tok, pos, seg
 
 
-def logits(cfg: dict, seed: int, seqs: Sequence[np.ndarray],
-           rows: Sequence[Tuple[int, int]], mode: str = "f32") -> jax.Array:
+def forward(s: dict, seed: int, seqs: Sequence[np.ndarray],
+            rows: Sequence[Tuple[int, int]], mode: str,
+            period: Sequence[Tuple[Callable, Dict[str, Tuple[int, ...]]]],
+            blocks: int) -> jax.Array:
     """Logits (len(rows), vocab) float32 at ``rows``: (sequence index,
-    position) pairs, of the packed ``seqs`` under the model of ``cfg``
-    with weights from ``seed``."""
-    s = sizes(cfg)
+    position) pairs, of the packed ``seqs``, with weights from ``seed``.
+
+    The model is ``blocks`` repeats of ``period``, the layer pattern: each
+    entry a layer function, jitted as ``f(x, w, pos, seg, s, mode)`` with
+    ``s`` (the sizes, as sorted items) and ``mode`` static, and the shapes
+    of its weights. Layer ``j`` of repeat ``b`` takes its weights from the
+    program's leaves ``blocks/<j>/...`` at index ``b``, one layer at a
+    time. Embedding, final norm and head are the dense decoder's."""
     dtype = MODES[mode][0]
     key = weights.base_key(seed)
     tok, pos, seg = pack(seqs)
@@ -167,18 +181,28 @@ def logits(cfg: dict, seed: int, seqs: Sequence[np.ndarray],
     if not s["tie"]:
         del embed
     pos_d, seg_d = jnp.asarray(pos), jnp.asarray(seg)
-    shapes = tuple(sorted(layer_shapes(s).items()))
     frozen = tuple(sorted(s.items()))
-    for i in range(s["layers"]):
-        w = _layer_weights(key, i, shapes, dtype)
-        x = _layer(x, w, pos_d, seg_d, frozen, mode)
-        del w
+    period = [(fn, tuple(sorted(shapes.items()))) for fn, shapes in period]
+    for b in range(blocks):
+        for j, (fn, shapes) in enumerate(period):
+            w = _layer_weights(key, b, shapes, dtype, j)
+            x = fn(x, w, pos_d, seg_d, frozen, mode)
+            del w
     rows_x = x[jnp.asarray(at)]
     del x
     norm = _leaf(key, "final_norm/scale", (s["d"],), jnp.float32)
     head = (embed.T if s["tie"]
             else _leaf(key, "lm_head", (s["d"], s["v"]), dtype))
     return _head(rows_x, norm, head, frozen, mode)
+
+
+def logits(cfg: dict, seed: int, seqs: Sequence[np.ndarray],
+           rows: Sequence[Tuple[int, int]], mode: str = "f32") -> jax.Array:
+    """``forward`` of the dense decoder ``cfg`` describes: one layer kind,
+    ``num_hidden_layers`` deep."""
+    s = sizes(cfg)
+    return forward(s, seed, seqs, rows, mode, [(layer, layer_shapes(s))],
+                   s["layers"])
 
 
 def served_rows(prompts: Sequence[np.ndarray], served: Sequence[np.ndarray]

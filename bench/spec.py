@@ -3,14 +3,17 @@ model configuration, one per traffic mix, and one reader per per-layer
 metric.
 
 Nothing here imports the serving program. A new cell, configuration or
-per-layer metric is a new file plus a new entry in ``BENCHMARK.json``; no
-code here names one.
+per-layer metric is a new file plus a new entry in ``BENCHMARK.json``, and a
+new architecture is one more file, its model description; no code here names
+one.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -78,7 +81,9 @@ def check_config(cfg: dict, entry: Optional[dict] = None) -> None:
     must hold the same value in the file as it is run, unless the key is
     listed in ``reduced`` or ``assumed``. ``reduced`` never names a width,
     and lists only keys that were cut. The entry in ``BENCHMARK.json``, when
-    given, must list the same ``reduced`` keys and the same source.
+    given, must list the same ``reduced`` keys and the same source. A file
+    that names no ``model`` states every key the dense description maps
+    (``ARCH_KEYS``); a named description reads its own.
     """
     name = cfg.get("name", "?")
     published = cfg["published"]
@@ -103,7 +108,7 @@ def check_config(cfg: dict, entry: Optional[dict] = None) -> None:
             raise SpecError(f"{name}: {key!r} is {cfg[key]!r} but the source "
                             f"publishes {value!r}; list it in reduced or "
                             "assumed")
-    for key in ARCH_KEYS:
+    for key in (ARCH_KEYS if "model" not in cfg else ()):
         if key not in cfg:
             raise SpecError(f"{name}: the configuration does not state "
                             f"{key!r}")
@@ -155,11 +160,42 @@ def reader(metric: str, root: Path = ROOT) -> Callable[[Any], Optional[float]]:
     path = root / "bench" / "layers" / f"{metric}.py"
     if not path.exists():
         raise SpecError(f"no reader {path} for per-layer metric {metric!r}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_layer_{metric.replace('.', '_').replace('-', '_')}", path)
+    return _load(path, f"bench_layer_{metric}").read
+
+
+#: the description of a configuration file that names no ``model``
+DEFAULT_MODEL = "dense"
+
+
+def model(cfg: dict, root: Path = ROOT) -> ModuleType:
+    """The configuration's model description, ``bench/models/<name>.py``
+    for the file's ``"model"`` (the dense GQA decoder where it names none).
+
+    A description provides ``arch_config(cfg)``, the program's
+    ``ArchConfig``: the layer pattern, expert and window fields, and as
+    ``num_blocks`` the repeats of the pattern (``num_hidden_layers`` over
+    its length); ``sizes(cfg)``, the sizes its reference reads, the vocabulary
+    as ``v``; ``logits(cfg, seed, seqs, rows, mode)``, its plain reference
+    (``bench.reference.logits``'s signature and ``MODES``), with weights
+    from ``bench.weights.leaf`` by the program's leaf paths; and
+    ``work(cfg)``, its counts (``bench.flops.Stack``: ``prefill_flops``,
+    ``decode_flops``, ``head_params`` and the ``kinds`` of its layers)."""
+    name = cfg.get("model", DEFAULT_MODEL)
+    path = root / "bench" / "models" / f"{name}.py"
+    if not path.exists():
+        raise SpecError(f"no model description {path} for {name!r}")
+    return _load(path, f"bench_model_{name}")
+
+
+def _load(path: Path, name: str) -> ModuleType:
+    """The module in ``path``, loaded anew (and registered, so that the
+    classes it defines find it)."""
+    name = name.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
 def peaks(device_kind: str, root: Path = ROOT) -> Dict[str, float]:
